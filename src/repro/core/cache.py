@@ -27,6 +27,7 @@ from typing import NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.core.hashing import EMPTY_HI, EMPTY_LO, Key64, bucket_index, \
     hash_u32
 
@@ -236,22 +237,26 @@ def lookup_dual(direct: CacheState, failover: CacheState, keys: Key64,
     backend this is a SINGLE fused kernel launch (``cache_probe_dual``);
     on jnp it is two reference lookups — same results either way.
     """
-    if backend == "pallas":
-        from repro.kernels import cache_probe as probe_kernels
+    with jax.named_scope(trace.PROBE):
+        if backend == "pallas":
+            from repro.kernels import cache_probe as probe_kernels
 
-        b_d = bucket_index(keys, direct.n_buckets)
-        b_f = bucket_index(keys, failover.n_buckets)
-        (hd, vd, ad, wd), (hf, vf, af, wf) = probe_kernels.cache_probe_dual(
-            direct.key_hi, direct.key_lo, direct.write_ts, direct.values,
-            failover.key_hi, failover.key_lo, failover.write_ts,
-            failover.values, keys.hi, keys.lo, b_d, b_f,
-            now_ms, direct_ttl_ms, failover_ttl_ms)
-        return (LookupResult(hit=hd, values=vd, age_ms=ad, bucket=b_d,
-                             way=wd),
-                LookupResult(hit=hf, values=vf, age_ms=af, bucket=b_f,
-                             way=wf))
-    return (lookup(direct, keys, now_ms, direct_ttl_ms, backend=backend),
-            lookup(failover, keys, now_ms, failover_ttl_ms, backend=backend))
+            b_d = bucket_index(keys, direct.n_buckets)
+            b_f = bucket_index(keys, failover.n_buckets)
+            ((hd, vd, ad, wd),
+             (hf, vf, af, wf)) = probe_kernels.cache_probe_dual(
+                direct.key_hi, direct.key_lo, direct.write_ts, direct.values,
+                failover.key_hi, failover.key_lo, failover.write_ts,
+                failover.values, keys.hi, keys.lo, b_d, b_f,
+                now_ms, direct_ttl_ms, failover_ttl_ms)
+            return (LookupResult(hit=hd, values=vd, age_ms=ad, bucket=b_d,
+                                 way=wd),
+                    LookupResult(hit=hf, values=vf, age_ms=af, bucket=b_f,
+                                 way=wf))
+        return (lookup(direct, keys, now_ms, direct_ttl_ms,
+                       backend=backend),
+                lookup(failover, keys, now_ms, failover_ttl_ms,
+                       backend=backend))
 
 
 def _dedupe(keys: Key64, live: jnp.ndarray, salt=None) -> jnp.ndarray:
@@ -460,20 +465,21 @@ def plan_insert(state: CacheState, keys: Key64, now_ms, ttl_ms,
     The returned ``winner`` already has residual slot collisions resolved;
     ``(winner, bucket, way)`` target slots are distinct.
     """
-    B = keys.hi.shape[0]
-    now_ms = jnp.int32(now_ms)
-    bucket, match, empty, ts = _probe(state, keys, bucket=buckets)
-    expired = (~empty) & ((now_ms - ts) > _ttl_cols(ttl_ms))  # erlint: allow[ER004] — ~empty masks the wrap
-    live = (write_mask if write_mask is not None
-            else jnp.ones((B,), bool))
-    winner = _dedupe(keys, live, salt=dedupe_salt)
-    rank = _bucket_rank(bucket, winner, state.n_buckets)
-    recency = jnp.maximum(ts, state.last_access_ts[bucket])
-    way = _choose_way(match, empty, expired, ts, rank, lru=evict_lru,
-                      recency=recency)
-    winner = _resolve_collisions(winner, bucket, way, state.n_buckets,
-                                 state.ways)
-    return winner, bucket, way
+    with jax.named_scope(trace.PLAN):
+        B = keys.hi.shape[0]
+        now_ms = jnp.int32(now_ms)
+        bucket, match, empty, ts = _probe(state, keys, bucket=buckets)
+        expired = (~empty) & ((now_ms - ts) > _ttl_cols(ttl_ms))  # erlint: allow[ER004] — ~empty masks the wrap
+        live = (write_mask if write_mask is not None
+                else jnp.ones((B,), bool))
+        winner = _dedupe(keys, live, salt=dedupe_salt)
+        rank = _bucket_rank(bucket, winner, state.n_buckets)
+        recency = jnp.maximum(ts, state.last_access_ts[bucket])
+        way = _choose_way(match, empty, expired, ts, rank, lru=evict_lru,
+                          recency=recency)
+        winner = _resolve_collisions(winner, bucket, way, state.n_buckets,
+                                     state.ways)
+        return winner, bucket, way
 
 
 def _scatter_insert(state: CacheState, keys: Key64, values, ts_vec,
@@ -482,16 +488,17 @@ def _scatter_insert(state: CacheState, keys: Key64, values, ts_vec,
     out-of-range bucket. A write resets the slot's last_access_ts to the
     write timestamp — stale touch coordinates from a previous occupant
     must never boost the new entry's recency."""
-    b_w = jnp.where(winner, bucket, jnp.int32(state.n_buckets))
-    return CacheState(
-        key_hi=state.key_hi.at[b_w, way].set(keys.hi, mode="drop"),
-        key_lo=state.key_lo.at[b_w, way].set(keys.lo, mode="drop"),
-        write_ts=state.write_ts.at[b_w, way].set(ts_vec, mode="drop"),
-        values=state.values.at[b_w, way].set(
-            values.astype(state.values.dtype), mode="drop"),
-        last_access_ts=state.last_access_ts.at[b_w, way].set(ts_vec,
-                                                             mode="drop"),
-    )
+    with jax.named_scope(trace.SCATTER):
+        b_w = jnp.where(winner, bucket, jnp.int32(state.n_buckets))
+        return CacheState(
+            key_hi=state.key_hi.at[b_w, way].set(keys.hi, mode="drop"),
+            key_lo=state.key_lo.at[b_w, way].set(keys.lo, mode="drop"),
+            write_ts=state.write_ts.at[b_w, way].set(ts_vec, mode="drop"),
+            values=state.values.at[b_w, way].set(
+                values.astype(state.values.dtype), mode="drop"),
+            last_access_ts=state.last_access_ts.at[b_w, way].set(ts_vec,
+                                                                 mode="drop"),
+        )
 
 
 def _ts_vector(values, now_ms, ts_ms) -> jnp.ndarray:
@@ -582,37 +589,39 @@ def insert_dual(direct: CacheState, failover: CacheState, keys: Key64,
             else jnp.ones((B,), bool))
     ts_vec = _ts_vector(values, now_ms, ts_ms)
 
-    winner = _dedupe(keys, live, salt=dedupe_salt)
+    with jax.named_scope(trace.PLAN):
+        winner = _dedupe(keys, live, salt=dedupe_salt)
 
-    b_d, match_d, empty_d, ts_d = _probe(direct, keys, bucket=buckets_d)
-    rank_d = _bucket_rank(b_d, winner, direct.n_buckets)
-    expired_d = (~empty_d) & ((now_ms - ts_d) > _ttl_cols(direct_ttl_ms))  # erlint: allow[ER004] — ~empty_d masks the wrap
-    way_d = _choose_way(match_d, empty_d, expired_d, ts_d, rank_d,
-                        lru=evict_lru,
-                        recency=jnp.maximum(ts_d,
-                                            direct.last_access_ts[b_d]))
-    win_d = _resolve_collisions(winner, b_d, way_d, direct.n_buckets,
-                                direct.ways)
+        b_d, match_d, empty_d, ts_d = _probe(direct, keys, bucket=buckets_d)
+        rank_d = _bucket_rank(b_d, winner, direct.n_buckets)
+        expired_d = (~empty_d) & ((now_ms - ts_d) > _ttl_cols(direct_ttl_ms))  # erlint: allow[ER004] — ~empty_d masks the wrap
+        way_d = _choose_way(match_d, empty_d, expired_d, ts_d, rank_d,
+                            lru=evict_lru,
+                            recency=jnp.maximum(ts_d,
+                                                direct.last_access_ts[b_d]))
+        win_d = _resolve_collisions(winner, b_d, way_d, direct.n_buckets,
+                                    direct.ways)
     new_direct = _scatter_insert(direct, keys, values, ts_vec,
                                  win_d, b_d, way_d)
 
-    # Probe results must come from the failover's own contents; only the
-    # bucket mapping (and therefore the ranks) can be shared across caches.
-    b_f, match_f, empty_f, ts_f = _probe(failover, keys, bucket=buckets_f)
-    same_mapping = ((buckets_d is None and buckets_f is None
-                     and failover.n_buckets == direct.n_buckets)
-                    or (buckets_d is not None and buckets_d is buckets_f))
-    if same_mapping:
-        rank_f = rank_d                       # identical bucket mapping
-    else:
-        rank_f = _bucket_rank(b_f, winner, failover.n_buckets)
-    expired_f = (~empty_f) & ((now_ms - ts_f) > _ttl_cols(failover_ttl_ms))  # erlint: allow[ER004] — ~empty_f masks the wrap
-    way_f = _choose_way(match_f, empty_f, expired_f, ts_f, rank_f,
-                        lru=evict_lru,
-                        recency=jnp.maximum(ts_f,
-                                            failover.last_access_ts[b_f]))
-    win_f = _resolve_collisions(winner, b_f, way_f, failover.n_buckets,
-                                failover.ways)
+    with jax.named_scope(trace.PLAN):
+        # Probe results must come from the failover's own contents; only the
+        # bucket mapping (and therefore the ranks) can be shared across caches.
+        b_f, match_f, empty_f, ts_f = _probe(failover, keys, bucket=buckets_f)
+        same_mapping = ((buckets_d is None and buckets_f is None
+                         and failover.n_buckets == direct.n_buckets)
+                        or (buckets_d is not None and buckets_d is buckets_f))
+        if same_mapping:
+            rank_f = rank_d                       # identical bucket mapping
+        else:
+            rank_f = _bucket_rank(b_f, winner, failover.n_buckets)
+        expired_f = (~empty_f) & ((now_ms - ts_f) > _ttl_cols(failover_ttl_ms))  # erlint: allow[ER004] — ~empty_f masks the wrap
+        way_f = _choose_way(match_f, empty_f, expired_f, ts_f, rank_f,
+                            lru=evict_lru,
+                            recency=jnp.maximum(ts_f,
+                                                failover.last_access_ts[b_f]))
+        win_f = _resolve_collisions(winner, b_f, way_f, failover.n_buckets,
+                                    failover.ways)
     new_failover = _scatter_insert(failover, keys, values, ts_vec,
                                    win_f, b_f, way_f)
     return new_direct, new_failover
@@ -834,27 +843,28 @@ def lookup_dual_multi(direct: MultiCacheState, failover: MultiCacheState,
 
     Returns (LookupResult_direct, LookupResult_failover).
     """
-    slots = jnp.asarray(slots, jnp.int32)
-    b_d, b_f = _pooled_bucket_pair(direct, failover, policy, slots, keys)
-    if backend == "pallas":
-        from repro.kernels import cache_probe as probe_kernels
+    with jax.named_scope(trace.PROBE):
+        slots = jnp.asarray(slots, jnp.int32)
+        b_d, b_f = _pooled_bucket_pair(direct, failover, policy, slots, keys)
+        if backend == "pallas":
+            from repro.kernels import cache_probe as probe_kernels
 
-        fd, ff = direct.flat(), failover.flat()
-        ((hd, vd, ad, wd),
-         (hf, vf, af, wf)) = probe_kernels.cache_probe_dual_multi(
-            fd.key_hi, fd.key_lo, fd.write_ts, fd.values,
-            ff.key_hi, ff.key_lo, ff.write_ts, ff.values,
-            keys.hi, keys.lo, slots, b_d, b_f, policy.table(), now_ms)
-        return (LookupResult(hit=hd, values=vd, age_ms=ad, bucket=b_d,
-                             way=wd),
-                LookupResult(hit=hf, values=vf, age_ms=af, bucket=b_f,
-                             way=wf))
-    if backend != "jnp":
-        raise ValueError(f"unknown cache backend: {backend!r}")
-    return (lookup(direct.flat(), keys, now_ms, policy.ttl_ms[slots],
-                   buckets=b_d),
-            lookup(failover.flat(), keys, now_ms,
-                   policy.failover_ttl_ms[slots], buckets=b_f))
+            fd, ff = direct.flat(), failover.flat()
+            ((hd, vd, ad, wd),
+             (hf, vf, af, wf)) = probe_kernels.cache_probe_dual_multi(
+                fd.key_hi, fd.key_lo, fd.write_ts, fd.values,
+                ff.key_hi, ff.key_lo, ff.write_ts, ff.values,
+                keys.hi, keys.lo, slots, b_d, b_f, policy.table(), now_ms)
+            return (LookupResult(hit=hd, values=vd, age_ms=ad, bucket=b_d,
+                                 way=wd),
+                    LookupResult(hit=hf, values=vf, age_ms=af, bucket=b_f,
+                                 way=wf))
+        if backend != "jnp":
+            raise ValueError(f"unknown cache backend: {backend!r}")
+        return (lookup(direct.flat(), keys, now_ms, policy.ttl_ms[slots],
+                       buckets=b_d),
+                lookup(failover.flat(), keys, now_ms,
+                       policy.failover_ttl_ms[slots], buckets=b_f))
 
 
 def insert_dual_multi(direct: MultiCacheState, failover: MultiCacheState,

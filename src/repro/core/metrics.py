@@ -23,7 +23,6 @@ class ServingCounters:
     fallbacks: int = 0              # requests served by the *model fallback*
                                     # (default embedding) — the paper's
                                     # "model fallback rate"
-    cache_writes: int = 0
     combined_writes: int = 0
     # SLA admission-control ledger (DESIGN.md §8). Without a configured
     # inference budget every miss is admitted: `admitted` then equals the

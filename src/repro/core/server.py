@@ -60,6 +60,7 @@ import jax.numpy as jnp
 
 from repro.core import cache as cache_lib
 from repro.core import ratelimit as rl_lib
+from repro.core import trace
 from repro.core import writebuf as wb_lib
 from repro.core.cache import CacheState
 from repro.core.config import CacheConfig
@@ -245,7 +246,9 @@ def _serve_many_scan(step_fn, flush_fn, state, payload, now_ms,
     if chaos is not None:
         xs = xs + (chaos,)
     (state, acc), ys = jax.lax.scan(body, (state, acc0), xs)
-    return flush_fn(state, now_ms[-1]), acc, ys
+    with jax.named_scope(trace.TAIL_FLUSH):
+        state = flush_fn(state, now_ms[-1])
+    return state, acc, ys
 
 
 def _serve_tail(tower_fn: Callable, miss_budget: int, fallback_value: float,
@@ -300,124 +303,128 @@ def _serve_tail(tower_fn: Callable, miss_budget: int, fallback_value: float,
         fo_strict_hit = fo.hit
 
     # (2) compaction: rows that RUN the tower first, stable ---------------
-    order = jnp.argsort(~infer, stable=True)            # inference rows first
-    sel = order[:miss_budget]                           # batch indices
-    sel_is_inf = infer[sel]                             # tail may be hits
+    with jax.named_scope(trace.ADMIT):
+        order = jnp.argsort(~infer, stable=True)        # inference rows first
+        sel = order[:miss_budget]                       # batch indices
+        sel_is_inf = infer[sel]                         # tail may be hits
+        sel_features = jax.tree_util.tree_map(lambda x: x[sel], features)
+    with jax.named_scope(trace.TOWER):
+        towered = tower_fn(params, sel_features)        # (miss_budget, D)
+        towered = towered.astype(direct.values.dtype)
 
-    sel_features = jax.tree_util.tree_map(lambda x: x[sel], features)
-    towered = tower_fn(params, sel_features)            # (miss_budget, D)
-    towered = towered.astype(direct.values.dtype)
+    with jax.named_scope(trace.SERVE):
+        sel_failed = failure_mask[sel]
+        sel_ok = sel_is_inf & ~sel_failed               # produced embedding
 
-    sel_failed = failure_mask[sel]
-    sel_ok = sel_is_inf & ~sel_failed                   # produced embedding
+        # (3) scatter computed rows back (broadcast to duplicates when
+        # coalescing); the degradation chain for the rest — deferred (over
+        # budget) ∪ overflow (over miss_budget) ∪ failed all consult the
+        # failover probe, then the default embedding.
+        if src_row is None:
+            computed = jnp.zeros((B,), bool).at[sel].set(sel_ok)
+            emb = direct.values
+            emb = emb.at[sel].set(
+                jnp.where(sel_ok[:, None], towered, emb[sel]))
+        else:
+            src = jnp.maximum(src_row, 0)     # -1 (no group) rows gated below
+            ok_row = jnp.zeros((B,), bool).at[sel].set(sel_ok)
+            computed = admit & ok_row[src]
+            tower_rows = jnp.zeros_like(direct.values).at[sel].set(
+                jnp.where(sel_is_inf[:, None], towered, 0))
+            emb = jnp.where(computed[:, None], tower_rows[src], direct.values)
+        unresolved = miss & ~computed
+        use_fo = unresolved & fo.hit
+        emb = jnp.where(use_fo[:, None], fo.values.astype(emb.dtype), emb)
+        fallback = unresolved & ~fo.hit
+        emb = jnp.where(fallback[:, None],
+                        jnp.full_like(emb, fallback_value), emb)
 
-    # (3) scatter computed rows back (broadcast to duplicates when
-    # coalescing); the degradation chain for the rest — deferred (over
-    # budget) ∪ overflow (over miss_budget) ∪ failed all consult the
-    # failover probe, then the default embedding.
-    if src_row is None:
-        computed = jnp.zeros((B,), bool).at[sel].set(sel_ok)
-        emb = direct.values
-        emb = emb.at[sel].set(jnp.where(sel_ok[:, None], towered, emb[sel]))
-    else:
-        src = jnp.maximum(src_row, 0)     # -1 (no group) rows gated below
-        ok_row = jnp.zeros((B,), bool).at[sel].set(sel_ok)
-        computed = admit & ok_row[src]
-        tower_rows = jnp.zeros_like(direct.values).at[sel].set(
-            jnp.where(sel_is_inf[:, None], towered, 0))
-        emb = jnp.where(computed[:, None], tower_rows[src], direct.values)
-    unresolved = miss & ~computed
-    use_fo = unresolved & fo.hit
-    emb = jnp.where(use_fo[:, None], fo.values.astype(emb.dtype), emb)
-    fallback = unresolved & ~fo.hit
-    emb = jnp.where(fallback[:, None],
-                    jnp.full_like(emb, fallback_value), emb)
+        source = jnp.where(
+            direct.hit, SRC_DIRECT,
+            jnp.where(computed, SRC_COMPUTED,
+                      jnp.where(use_fo, SRC_FAILOVER, SRC_FALLBACK))
+        ).astype(jnp.int32)
+        age = jnp.where(direct.hit, direct.age_ms,
+                        jnp.where(computed, 0,
+                                  jnp.where(use_fo, fo.age_ms, -1)))
 
-    source = jnp.where(
-        direct.hit, SRC_DIRECT,
-        jnp.where(computed, SRC_COMPUTED,
-                  jnp.where(use_fo, SRC_FAILOVER, SRC_FALLBACK))
-    ).astype(jnp.int32)
-    age = jnp.where(direct.hit, direct.age_ms,
-                    jnp.where(computed, 0,
-                              jnp.where(use_fo, fo.age_ms, -1)))
+        # (4) async cache update: append computed rows to the write buffer ----
+        sel_keys = Key64(hi=keys.hi[sel], lo=keys.lo[sel])
+        wb_mask = sel_ok if write_drop is None else sel_ok & ~write_drop[sel]
+        new_wb = wb_lib.append(
+            writebuf, sel_keys, towered, now_ms, mask=wb_mask,
+            model_ids=None if model_slots is None else model_slots[sel])
 
-    # (4) async cache update: append computed rows to the write buffer ----
-    sel_keys = Key64(hi=keys.hi[sel], lo=keys.lo[sel])
-    wb_mask = sel_ok if write_drop is None else sel_ok & ~write_drop[sel]
-    new_wb = wb_lib.append(
-        writebuf, sel_keys, towered, now_ms, mask=wb_mask,
-        model_ids=None if model_slots is None else model_slots[sel])
+        def count(flag):
+            return jnp.sum(flag.astype(jnp.int32))
 
-    def count(flag):
-        return jnp.sum(flag.astype(jnp.int32))
+        # Staleness accounting of the failover serves (float32: int32 would
+        # wrap on a batch of hour-scale ages) — the SLA trade's cost side.
+        fo_age_sum = jnp.sum(jnp.where(use_fo, fo.age_ms, 0)
+                             .astype(jnp.float32))
+        # age >= 0: a hit written and read in the same millisecond is a
+        # legitimate age-0 serve and must count in both numerator and
+        # denominator (misses carry age -1 and stay excluded).
+        age_sum = jnp.sum(jnp.where(age >= 0, age, 0).astype(jnp.float32))
+        age_served = jnp.sum((age >= 0).astype(jnp.int32))
+        stats = {
+            "requests": jnp.int32(B),
+            "direct_hits": count(direct.hit),
+            # actual tower forward passes: one per UNIQUE admitted user when
+            # coalescing, one per admitted miss row otherwise
+            "tower_inferences": count(sel_is_inf),
+            "tower_failures": count(sel_is_inf & sel_failed),
+            # wanted inferences beyond the miss-budget window (never attempted)
+            "overflow": count(infer) - count(sel_is_inf),
+            # admission-control ledger: admitted counts every COVERED request
+            # row (duplicates of an admitted user included, so deferred keeps
+            # its per-request meaning); deferred = misses the budget gated off
+            "admitted": count(admit),
+            "deferred": count(miss) - count(admit),
+            # strict-TTL failover recoveries (the pre-admission meaning) vs
+            # ALL failover-tier serves on the degradation chain
+            "failover_hits": count(use_fo & fo_strict_hit),
+            "failover_serves": count(use_fo),
+            "fallbacks": count(fallback),
+            "failover_stale_ms": fo_age_sum /
+                jnp.maximum(count(use_fo), 1).astype(jnp.float32),
+            "mean_age_ms": age_sum /
+                jnp.maximum(age_served, 1).astype(jnp.float32),
+            # Additive twins of the mean keys above: means cannot be summed
+            # across steps, so serve_many's device-resident accumulator
+            # (DESIGN.md §9) carries the raw sums and derives means on the
+            # host after the single per-dispatch fetch.
+            "failover_stale_sum_ms": fo_age_sum,
+            "served_age_sum_ms": age_sum,
+            "served_age_count": age_served,
+            # tower-served request rows (duplicates included): with
+            # direct_hits / failover_serves / fallbacks this partitions the
+            # batch — the degradation ledger's conservation identity
+            "computed_serves": count(computed),
+        }
+        if write_drop is not None:
+            stats["blackout_write_drops"] = count(sel_ok & write_drop[sel])
+        if model_slots is not None:
+            # per-model (M,) breakdowns for Table-1-style accounting
+            def per_model(flag, dtype=jnp.int32):
+                return (jnp.zeros((n_models,), dtype)
+                        .at[model_slots].add(flag.astype(dtype)))
 
-    # Staleness accounting of the failover serves (float32: int32 would
-    # wrap on a batch of hour-scale ages) — the SLA trade's cost side.
-    fo_age_sum = jnp.sum(jnp.where(use_fo, fo.age_ms, 0)
-                         .astype(jnp.float32))
-    # age >= 0: a hit written and read in the same millisecond is a
-    # legitimate age-0 serve and must count in both numerator and
-    # denominator (misses carry age -1 and stay excluded).
-    age_sum = jnp.sum(jnp.where(age >= 0, age, 0).astype(jnp.float32))
-    age_served = jnp.sum((age >= 0).astype(jnp.int32))
-    stats = {
-        "requests": jnp.int32(B),
-        "direct_hits": count(direct.hit),
-        # actual tower forward passes: one per UNIQUE admitted user when
-        # coalescing, one per admitted miss row otherwise
-        "tower_inferences": count(sel_is_inf),
-        "tower_failures": count(sel_is_inf & sel_failed),
-        # wanted inferences beyond the miss-budget window (never attempted)
-        "overflow": count(infer) - count(sel_is_inf),
-        # admission-control ledger: admitted counts every COVERED request
-        # row (duplicates of an admitted user included, so deferred keeps
-        # its per-request meaning); deferred = misses the budget gated off
-        "admitted": count(admit),
-        "deferred": count(miss) - count(admit),
-        # strict-TTL failover recoveries (the pre-admission meaning) vs
-        # ALL failover-tier serves on the degradation chain
-        "failover_hits": count(use_fo & fo_strict_hit),
-        "failover_serves": count(use_fo),
-        "fallbacks": count(fallback),
-        "failover_stale_ms": fo_age_sum /
-            jnp.maximum(count(use_fo), 1).astype(jnp.float32),
-        "mean_age_ms": age_sum /
-            jnp.maximum(age_served, 1).astype(jnp.float32),
-        # Additive twins of the mean keys above: means cannot be summed
-        # across steps, so serve_many's device-resident accumulator
-        # (DESIGN.md §9) carries the raw sums and derives means on the
-        # host after the single per-dispatch fetch.
-        "failover_stale_sum_ms": fo_age_sum,
-        "served_age_sum_ms": age_sum,
-        "served_age_count": age_served,
-        # tower-served request rows (duplicates included): with
-        # direct_hits / failover_serves / fallbacks this partitions the
-        # batch — the degradation ledger's conservation identity
-        "computed_serves": count(computed),
-    }
-    if write_drop is not None:
-        stats["blackout_write_drops"] = count(sel_ok & write_drop[sel])
-    if model_slots is not None:
-        # per-model (M,) breakdowns for Table-1-style accounting
-        def per_model(flag, dtype=jnp.int32):
-            return (jnp.zeros((n_models,), dtype)
-                    .at[model_slots].add(flag.astype(dtype)))
-
-        stats["per_model_requests"] = per_model(jnp.ones((B,), bool))
-        stats["per_model_direct_hits"] = per_model(direct.hit)
-        stats["per_model_failover_hits"] = per_model(use_fo & fo_strict_hit)
-        stats["per_model_fallbacks"] = per_model(fallback)
-        stats["per_model_admitted"] = per_model(admit)
-        stats["per_model_deferred"] = per_model(miss) - per_model(admit)
-        stats["per_model_failover_serves"] = per_model(use_fo)
-        pm_stale_sum = per_model(jnp.where(use_fo, fo.age_ms, 0),
-                                 jnp.float32)
-        stats["per_model_failover_stale_ms"] = (
-            pm_stale_sum
-            / jnp.maximum(per_model(use_fo), 1).astype(jnp.float32))
-        stats["per_model_failover_stale_sum_ms"] = pm_stale_sum
-    return emb, source, age.astype(jnp.int32), new_wb, stats
+            stats["per_model_requests"] = per_model(jnp.ones((B,), bool))
+            stats["per_model_direct_hits"] = per_model(direct.hit)
+            stats["per_model_failover_hits"] = per_model(
+                use_fo & fo_strict_hit)
+            stats["per_model_fallbacks"] = per_model(fallback)
+            stats["per_model_admitted"] = per_model(admit)
+            stats["per_model_deferred"] = per_model(miss) - per_model(admit)
+            stats["per_model_failover_serves"] = per_model(use_fo)
+            pm_stale_sum = per_model(jnp.where(use_fo, fo.age_ms, 0),
+                                     jnp.float32)
+            stats["per_model_failover_stale_ms"] = (
+                pm_stale_sum
+                / jnp.maximum(per_model(use_fo), 1).astype(jnp.float32))
+            stats["per_model_failover_stale_sum_ms"] = pm_stale_sum
+        return emb, source, age.astype(jnp.int32), new_wb, stats
 
 
 # ------------------------------------------------------- chaos serve hooks
@@ -568,74 +575,77 @@ class CachedEmbeddingServer:
                 state.direct, state.failover, keys, now_ms, cfg.cache_ttl_ms,
                 fo_ttl, backend=cfg.backend)
 
-        # (1a') bucket-range blackout: mask BEFORE touch/coalesce/admission
-        # so a blacked-out row is a cold miss to every downstream stage.
-        write_drop = None
-        if chaos is not None:
-            direct, write_drop = _chaos_blackout(direct, chaos)
+        with jax.named_scope(trace.ADMIT):
+            # (1a') bucket-range blackout: mask BEFORE touch/coalesce/admission
+            # so a blacked-out row is a cold miss to every downstream stage.
+            write_drop = None
+            if chaos is not None:
+                direct, write_drop = _chaos_blackout(direct, chaos)
 
-        # (1b) record hit coordinates for the deferred last-access bump —
-        # an O(B) ring scatter, never a cache-table write on this path.
-        # Statically skipped when the config doesn't track recency.
-        new_tb = state.touchbuf
-        if cfg.resolved_touch():
-            new_tb = wb_lib.touch_append(new_tb, direct, fo, now_ms)
+            # (1b) record hit coordinates for the deferred last-access bump —
+            # an O(B) ring scatter, never a cache-table write on this path.
+            # Statically skipped when the config doesn't track recency.
+            new_tb = state.touchbuf
+            if cfg.resolved_touch():
+                new_tb = wb_lib.touch_append(new_tb, direct, fo, now_ms)
 
-        # (1c) in-batch coalescing (DESIGN.md §9): dedupe the missed keys
-        # so admission and the tower operate on UNIQUE users — the first
-        # occurrence of each distinct key is the group's representative,
-        # duplicates reuse its embedding. Statically skipped (src_row
-        # None, the bit-exact legacy path) when the config doesn't opt in.
-        miss = ~direct.hit
-        infer = src_row = None
-        if cfg.coalesce_misses:
-            rep, src_row = cache_lib.dedupe_first_groups(keys, miss)
-            unit = rep                       # unit of inference demand
-        else:
-            unit = miss
-
-        # (1d) admission control: refill the token bucket, grant this
-        # step's tower inferences, defer the rest (statically skipped —
-        # admit=None — when no budget is configured). The grant is capped
-        # by the miss-budget compaction window too, and tokens are only
-        # charged for inferences that actually RUN (failed attempts
-        # included) — never for grants the window clips. With coalescing
-        # on, demand / grants / charges are all per UNIQUE user; an
-        # admitted user's duplicates ride along token-free.
-        admit = fo_strict = None
-        new_budget = state.budget
-        if self._admission:
-            fo_strict = fo.hit & (fo.age_ms <= jnp.int32(cfg.failover_ttl_ms))
-            demand = jnp.sum(unit.astype(jnp.int32))[None]       # (1,)
-            refilled = rl_lib.refill(state.budget, self._budget_rates,
-                                     self._budget_bursts)
-            grant = rl_lib.grant_from(
-                refilled, self._budget_limited, demand,
-                blocked=None if chaos is None else chaos.outage)
-            # batch-order rank of each inference unit: first grant[0] are
-            # admitted, clipped to the tower's execution window
-            u_i = unit.astype(jnp.int32)
-            rank = jnp.cumsum(u_i) - u_i                         # exclusive
-            infer = unit & (rank < jnp.minimum(grant[0],
-                                               jnp.int32(self.miss_budget)))
-            spent = jnp.sum(infer.astype(jnp.int32))[None]
-            new_budget = rl_lib.spend(refilled, self._budget_limited, spent)
+            # (1c) in-batch coalescing (DESIGN.md §9): dedupe the missed keys
+            # so admission and the tower operate on UNIQUE users — the first
+            # occurrence of each distinct key is the group's representative,
+            # duplicates reuse its embedding. Statically skipped (src_row
+            # None, the bit-exact legacy path) when the config doesn't opt in.
+            miss = ~direct.hit
+            infer = src_row = None
             if cfg.coalesce_misses:
-                # covered rows: every duplicate of an admitted user
-                admit = miss & infer[jnp.maximum(src_row, 0)]
+                rep, src_row = cache_lib.dedupe_first_groups(keys, miss)
+                unit = rep                       # unit of inference demand
             else:
-                admit = infer
-        elif cfg.coalesce_misses:
-            infer = rep          # window clipping happens in the tail
+                unit = miss
 
-        # (1e) bounded retry/backoff: re-attempt this step's failed
-        # inferences from the remaining tokens; recovered rows serve
-        # their computed embedding (failure bit cleared before the tail).
-        n_retries = n_retry_succ = None
-        if chaos is not None and chaos.retry_fail.shape[0] > 0:
-            failure_mask, new_budget, n_retries, n_retry_succ = \
-                _chaos_retries(chaos, infer, failure_mask, new_budget,
-                               self._budget_limited)
+            # (1d) admission control: refill the token bucket, grant this
+            # step's tower inferences, defer the rest (statically skipped —
+            # admit=None — when no budget is configured). The grant is capped
+            # by the miss-budget compaction window too, and tokens are only
+            # charged for inferences that actually RUN (failed attempts
+            # included) — never for grants the window clips. With coalescing
+            # on, demand / grants / charges are all per UNIQUE user; an
+            # admitted user's duplicates ride along token-free.
+            admit = fo_strict = None
+            new_budget = state.budget
+            if self._admission:
+                fo_strict = fo.hit & (
+                    fo.age_ms <= jnp.int32(cfg.failover_ttl_ms))
+                demand = jnp.sum(unit.astype(jnp.int32))[None]       # (1,)
+                refilled = rl_lib.refill(state.budget, self._budget_rates,
+                                         self._budget_bursts)
+                grant = rl_lib.grant_from(
+                    refilled, self._budget_limited, demand,
+                    blocked=None if chaos is None else chaos.outage)
+                # batch-order rank of each inference unit: first grant[0] are
+                # admitted, clipped to the tower's execution window
+                u_i = unit.astype(jnp.int32)
+                rank = jnp.cumsum(u_i) - u_i                     # exclusive
+                infer = unit & (rank < jnp.minimum(
+                    grant[0], jnp.int32(self.miss_budget)))
+                spent = jnp.sum(infer.astype(jnp.int32))[None]
+                new_budget = rl_lib.spend(refilled, self._budget_limited,
+                                          spent)
+                if cfg.coalesce_misses:
+                    # covered rows: every duplicate of an admitted user
+                    admit = miss & infer[jnp.maximum(src_row, 0)]
+                else:
+                    admit = infer
+            elif cfg.coalesce_misses:
+                infer = rep          # window clipping happens in the tail
+
+            # (1e) bounded retry/backoff: re-attempt this step's failed
+            # inferences from the remaining tokens; recovered rows serve
+            # their computed embedding (failure bit cleared before the tail).
+            n_retries = n_retry_succ = None
+            if chaos is not None and chaos.retry_fail.shape[0] > 0:
+                failure_mask, new_budget, n_retries, n_retry_succ = \
+                    _chaos_retries(chaos, infer, failure_mask, new_budget,
+                                   self._budget_limited)
 
         # (2)–(4): shared serve tail
         emb, source, age, new_wb, stats = _serve_tail(
@@ -929,79 +939,80 @@ class MultiModelServer:
                 state.direct, state.failover, self._probe_policy, slots,
                 keys, now_ms, backend=self.backend)
 
-        # (1a') pooled-bucket-range blackout, before every downstream stage
-        write_drop = None
-        if chaos is not None:
-            direct, write_drop = _chaos_blackout(direct, chaos)
+        with jax.named_scope(trace.ADMIT):
+            # (1a') pooled-bucket-range blackout, before every downstream stage
+            write_drop = None
+            if chaos is not None:
+                direct, write_drop = _chaos_blackout(direct, chaos)
 
-        # (1b) buffer hit coordinates (POOLED bucket indices) for deferred
-        # last-access bumps, gated by each query's per-model touch policy.
-        new_tb = state.touchbuf
-        if self._any_touch:
-            new_tb = wb_lib.touch_append(new_tb, direct, fo, now_ms,
-                                         mask=self.policy.touch[slots])
+            # (1b) buffer hit coordinates (POOLED bucket indices) for deferred
+            # last-access bumps, gated by each query's per-model touch policy.
+            new_tb = state.touchbuf
+            if self._any_touch:
+                new_tb = wb_lib.touch_append(new_tb, direct, fo, now_ms,
+                                             mask=self.policy.touch[slots])
 
-        # (1c) in-batch coalescing (DESIGN.md §9): dedupe missed
-        # (model, user) pairs — the dedupe is model-salted, so the same
-        # user queried for two models stays two inferences — gated per
-        # query by each model's coalesce policy. Misses of non-coalescing
-        # models each stand alone (their own representative).
-        miss = ~direct.hit
-        infer = src_row = None
-        if self._any_coalesce:
-            co = self.policy.coalesce[slots]
-            rep, src_co = cache_lib.dedupe_first_groups(keys, miss & co,
-                                                        salt=slots)
-            unit = rep | (miss & ~co)
-            src_row = jnp.where(miss & ~co, jnp.arange(B, dtype=jnp.int32),
-                                src_co)
-        else:
-            unit = miss
-
-        # (1d) admission control: ONE vectorized bucket update grants every
-        # model's tower share; each model's inference units (unique users
-        # when coalescing, miss rows otherwise) are admitted in batch
-        # order up to its grant, the rest deferred to the degradation
-        # chain. The total admission is additionally clipped to the
-        # miss-budget execution window (batch order across models), and
-        # each model's tokens are charged only for inferences that RUN —
-        # duplicates of an admitted user ride along token-free.
-        # Statically skipped when no model has a budget.
-        admit = fo_strict = None
-        new_budget = state.budget
-        if self._any_admission:
-            fo_strict = fo.hit & (fo.age_ms
-                                  <= self.policy.failover_ttl_ms[slots])
-            demand = (jnp.zeros((self.n_models,), jnp.int32)
-                      .at[slots].add(unit.astype(jnp.int32)))
-            refilled = rl_lib.refill(state.budget, self._budget_rates,
-                                     self._budget_bursts)
-            grant = rl_lib.grant_from(
-                refilled, self._budget_limited, demand,
-                blocked=None if chaos is None else chaos.outage)
-            rank = _per_model_miss_rank(slots, unit, self.n_models)
-            admit0 = unit & (rank < grant[slots])
-            a_i = admit0.astype(jnp.int32)
-            global_rank = jnp.cumsum(a_i) - a_i              # exclusive
-            infer = admit0 & (global_rank < jnp.int32(self.miss_budget))
-            spent = (jnp.zeros((self.n_models,), jnp.int32)
-                     .at[slots].add(infer.astype(jnp.int32)))
-            new_budget = rl_lib.spend(refilled, self._budget_limited,
-                                      spent)
+            # (1c) in-batch coalescing (DESIGN.md §9): dedupe missed
+            # (model, user) pairs — the dedupe is model-salted, so the same
+            # user queried for two models stays two inferences — gated per
+            # query by each model's coalesce policy. Misses of non-coalescing
+            # models each stand alone (their own representative).
+            miss = ~direct.hit
+            infer = src_row = None
             if self._any_coalesce:
-                admit = miss & infer[jnp.maximum(src_row, 0)]
+                co = self.policy.coalesce[slots]
+                rep, src_co = cache_lib.dedupe_first_groups(keys, miss & co,
+                                                            salt=slots)
+                unit = rep | (miss & ~co)
+                src_row = jnp.where(miss & ~co, jnp.arange(B, dtype=jnp.int32),
+                                    src_co)
             else:
-                admit = infer
-        elif self._any_coalesce:
-            infer = unit         # window clipping happens in the tail
+                unit = miss
 
-        # (1e) bounded retry/backoff from the remaining per-model tokens
-        n_retries = n_retry_succ = None
-        if chaos is not None and chaos.retry_fail.shape[0] > 0:
-            failure_mask, new_budget, n_retries, n_retry_succ = \
-                _chaos_retries(chaos, infer, failure_mask, new_budget,
-                               self._budget_limited, slots=slots,
-                               n_models=self.n_models)
+            # (1d) admission control: ONE vectorized bucket update grants every
+            # model's tower share; each model's inference units (unique users
+            # when coalescing, miss rows otherwise) are admitted in batch
+            # order up to its grant, the rest deferred to the degradation
+            # chain. The total admission is additionally clipped to the
+            # miss-budget execution window (batch order across models), and
+            # each model's tokens are charged only for inferences that RUN —
+            # duplicates of an admitted user ride along token-free.
+            # Statically skipped when no model has a budget.
+            admit = fo_strict = None
+            new_budget = state.budget
+            if self._any_admission:
+                fo_strict = fo.hit & (fo.age_ms
+                                      <= self.policy.failover_ttl_ms[slots])
+                demand = (jnp.zeros((self.n_models,), jnp.int32)
+                          .at[slots].add(unit.astype(jnp.int32)))
+                refilled = rl_lib.refill(state.budget, self._budget_rates,
+                                         self._budget_bursts)
+                grant = rl_lib.grant_from(
+                    refilled, self._budget_limited, demand,
+                    blocked=None if chaos is None else chaos.outage)
+                rank = _per_model_miss_rank(slots, unit, self.n_models)
+                admit0 = unit & (rank < grant[slots])
+                a_i = admit0.astype(jnp.int32)
+                global_rank = jnp.cumsum(a_i) - a_i              # exclusive
+                infer = admit0 & (global_rank < jnp.int32(self.miss_budget))
+                spent = (jnp.zeros((self.n_models,), jnp.int32)
+                         .at[slots].add(infer.astype(jnp.int32)))
+                new_budget = rl_lib.spend(refilled, self._budget_limited,
+                                          spent)
+                if self._any_coalesce:
+                    admit = miss & infer[jnp.maximum(src_row, 0)]
+                else:
+                    admit = infer
+            elif self._any_coalesce:
+                infer = unit         # window clipping happens in the tail
+
+            # (1e) bounded retry/backoff from the remaining per-model tokens
+            n_retries = n_retry_succ = None
+            if chaos is not None and chaos.retry_fail.shape[0] > 0:
+                failure_mask, new_budget, n_retries, n_retry_succ = \
+                    _chaos_retries(chaos, infer, failure_mask, new_budget,
+                                   self._budget_limited, slots=slots,
+                                   n_models=self.n_models)
 
         # (2)–(4): shared serve tail, with model-tagged buffer records
         emb, source, age, new_wb, stats = _serve_tail(
@@ -1106,7 +1117,8 @@ def serve_step_no_cache(tower_fn: Callable, params, keys: Key64, features,
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The cache-disabled baseline (the paper's "w/o cache" arm): every
     request pays a tower inference; failures go straight to model fallback."""
-    emb = tower_fn(params, features)
+    with jax.named_scope(trace.TOWER):
+        emb = tower_fn(params, features)
     B = emb.shape[0]
     if failure_mask is None:
         failure_mask = jnp.zeros((B,), bool)

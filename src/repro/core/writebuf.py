@@ -24,9 +24,11 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import cache as cache_lib
+from repro.core import trace
 from repro.core.hashing import Key64
 
 
@@ -189,8 +191,9 @@ def _apply_touches(buf: TouchBuffer, state: cache_lib.CacheState,
                    ) -> cache_lib.CacheState:
     """Scatter-max one cache's buffered bumps (records with ``bucket`` −1
     never hit that cache and are skipped)."""
-    return cache_lib.touch(state, bucket, way, buf.ts_ms,
-                           live=_touch_live(buf) & (bucket >= 0))
+    with jax.named_scope(trace.TOUCH):
+        return cache_lib.touch(state, bucket, way, buf.ts_ms,
+                               live=_touch_live(buf) & (bucket >= 0))
 
 
 def _apply_touches_dual(buf: Optional[TouchBuffer],
@@ -222,20 +225,21 @@ def flush(buf: WriteBuffer, state: cache_lib.CacheState, now_ms, ttl_ms,
     inserts/touches to a bucket-sharded table (DESIGN.md §11) — the rings
     stay replicated; results are bit-identical either way.
     """
-    if mesh is not None:
-        from repro.distributed import collectives as coll
+    with jax.named_scope(trace.FLUSH):
+        if mesh is not None:
+            from repro.distributed import collectives as coll
 
-        return coll.sharded_flush(mesh, buf, state, now_ms, ttl_ms,
-                                  evict_lru=evict_lru, touchbuf=touchbuf)
-    if touchbuf is not None:
-        state = _apply_touches(touchbuf, state, touchbuf.bucket_d,
-                               touchbuf.way_d)
-        touchbuf = touchbuf._replace(count=jnp.int32(0))
-    keys, values, ts, live, _ = _ring_order(buf)
-    new_state = cache_lib.insert(state, keys, values, now_ms, ttl_ms,
-                                 write_mask=live, ts_ms=ts,
-                                 evict_lru=evict_lru)
-    return new_state, buf._replace(count=jnp.int32(0)), touchbuf
+            return coll.sharded_flush(mesh, buf, state, now_ms, ttl_ms,
+                                      evict_lru=evict_lru, touchbuf=touchbuf)
+        if touchbuf is not None:
+            state = _apply_touches(touchbuf, state, touchbuf.bucket_d,
+                                   touchbuf.way_d)
+            touchbuf = touchbuf._replace(count=jnp.int32(0))
+        keys, values, ts, live, _ = _ring_order(buf)
+        new_state = cache_lib.insert(state, keys, values, now_ms, ttl_ms,
+                                     write_mask=live, ts_ms=ts,
+                                     evict_lru=evict_lru)
+        return new_state, buf._replace(count=jnp.int32(0)), touchbuf
 
 
 def flush_dual(buf: WriteBuffer, direct: cache_lib.CacheState,
@@ -254,21 +258,22 @@ def flush_dual(buf: WriteBuffer, direct: cache_lib.CacheState,
     recency planes BEFORE the inserts (see :func:`flush`). ``mesh`` routes
     everything to bucket-sharded tables (DESIGN.md §11), bit-identically.
     """
-    if mesh is not None:
-        from repro.distributed import collectives as coll
+    with jax.named_scope(trace.FLUSH):
+        if mesh is not None:
+            from repro.distributed import collectives as coll
 
-        return coll.sharded_flush_dual(mesh, buf, direct, failover, now_ms,
-                                       direct_ttl_ms, failover_ttl_ms,
-                                       evict_lru=evict_lru,
-                                       touchbuf=touchbuf)
-    direct, failover, touchbuf = _apply_touches_dual(touchbuf, direct,
-                                                     failover)
-    keys, values, ts, live, _ = _ring_order(buf)
-    new_direct, new_failover = cache_lib.insert_dual(
-        direct, failover, keys, values, now_ms, direct_ttl_ms,
-        failover_ttl_ms, write_mask=live, ts_ms=ts, evict_lru=evict_lru)
-    return (new_direct, new_failover, buf._replace(count=jnp.int32(0)),
-            touchbuf)
+            return coll.sharded_flush_dual(mesh, buf, direct, failover, now_ms,
+                                           direct_ttl_ms, failover_ttl_ms,
+                                           evict_lru=evict_lru,
+                                           touchbuf=touchbuf)
+        direct, failover, touchbuf = _apply_touches_dual(touchbuf, direct,
+                                                         failover)
+        keys, values, ts, live, _ = _ring_order(buf)
+        new_direct, new_failover = cache_lib.insert_dual(
+            direct, failover, keys, values, now_ms, direct_ttl_ms,
+            failover_ttl_ms, write_mask=live, ts_ms=ts, evict_lru=evict_lru)
+        return (new_direct, new_failover, buf._replace(count=jnp.int32(0)),
+                touchbuf)
 
 
 def flush_dual_multi(buf: WriteBuffer, direct: cache_lib.MultiCacheState,
@@ -291,20 +296,21 @@ def flush_dual_multi(buf: WriteBuffer, direct: cache_lib.MultiCacheState,
     routes everything to bucket-sharded stacked tiers (DESIGN.md §11),
     bit-identically.
     """
-    if mesh is not None:
-        from repro.distributed import collectives as coll
+    with jax.named_scope(trace.FLUSH):
+        if mesh is not None:
+            from repro.distributed import collectives as coll
 
-        return coll.sharded_flush_dual_multi(mesh, buf, direct, failover,
-                                             policy, now_ms,
-                                             touchbuf=touchbuf)
-    if touchbuf is not None:
-        flat_d, flat_f, touchbuf = _apply_touches_dual(
-            touchbuf, direct.flat(), failover.flat())
-        direct = direct.with_flat(flat_d)
-        failover = failover.with_flat(flat_f)
-    keys, values, ts, live, slots = _ring_order(buf)
-    new_direct, new_failover = cache_lib.insert_dual_multi(
-        direct, failover, policy, slots, keys, values, now_ms,
-        write_mask=live, ts_ms=ts)
-    return (new_direct, new_failover, buf._replace(count=jnp.int32(0)),
-            touchbuf)
+            return coll.sharded_flush_dual_multi(mesh, buf, direct, failover,
+                                                 policy, now_ms,
+                                                 touchbuf=touchbuf)
+        if touchbuf is not None:
+            flat_d, flat_f, touchbuf = _apply_touches_dual(
+                touchbuf, direct.flat(), failover.flat())
+            direct = direct.with_flat(flat_d)
+            failover = failover.with_flat(flat_f)
+        keys, values, ts, live, slots = _ring_order(buf)
+        new_direct, new_failover = cache_lib.insert_dual_multi(
+            direct, failover, policy, slots, keys, values, now_ms,
+            write_mask=live, ts_ms=ts)
+        return (new_direct, new_failover, buf._replace(count=jnp.int32(0)),
+                touchbuf)

@@ -177,6 +177,7 @@ def sharded_topk_scores(query, candidates, k_top: int, mesh: Mesh,
 # bucket coordinates), so servers and ring buffers upstream are unchanged.
 
 from repro.core import cache as cache_lib
+from repro.core import trace
 from repro.core import writebuf as wb_lib
 
 SHARD_AXIS = "shard"
@@ -226,44 +227,45 @@ def sharded_lookup_dual(mesh: Mesh, direct, failover, keys, now_ms,
     its local slabs, then the per-cache one-hot combine runs inside the
     same mapped computation. Results are replicated and bit-identical to
     the unsharded oracle."""
-    n_shards = mesh.shape[SHARD_AXIS]
-    nb_d, nb_f = direct.n_buckets, failover.n_buckets
-    nbl_d = cache_lib.shard_local_buckets(nb_d, n_shards)
-    nbl_f = cache_lib.shard_local_buckets(nb_f, n_shards)
+    with jax.named_scope(trace.PROBE):
+        n_shards = mesh.shape[SHARD_AXIS]
+        nb_d, nb_f = direct.n_buckets, failover.n_buckets
+        nbl_d = cache_lib.shard_local_buckets(nb_d, n_shards)
+        nbl_f = cache_lib.shard_local_buckets(nb_f, n_shards)
 
-    def body(d, f, k, now, ttl_d, ttl_f):
-        shard = _shard_index()
-        g_d = cache_lib.bucket_index(k, nb_d)
-        g_f = cache_lib.bucket_index(k, nb_f)
-        own_d, loc_d = cache_lib.route_buckets(g_d, shard, nb_d, nbl_d)
-        own_f, loc_f = cache_lib.route_buckets(g_f, shard, nb_f, nbl_f)
-        if backend == "pallas":
-            from repro.kernels import cache_probe as probe_kernels
+        def body(d, f, k, now, ttl_d, ttl_f):
+            shard = _shard_index()
+            g_d = cache_lib.bucket_index(k, nb_d)
+            g_f = cache_lib.bucket_index(k, nb_f)
+            own_d, loc_d = cache_lib.route_buckets(g_d, shard, nb_d, nbl_d)
+            own_f, loc_f = cache_lib.route_buckets(g_f, shard, nb_f, nbl_f)
+            if backend == "pallas":
+                from repro.kernels import cache_probe as probe_kernels
 
-            ((hd, vd, ad, wd),
-             (hf, vf, af, wf)) = probe_kernels.cache_probe_dual(
-                d.key_hi, d.key_lo, d.write_ts, d.values,
-                f.key_hi, f.key_lo, f.write_ts, f.values,
-                k.hi, k.lo, loc_d, loc_f, now, ttl_d, ttl_f)
-            rd = cache_lib.LookupResult(hd, vd, ad, loc_d, wd)
-            rf = cache_lib.LookupResult(hf, vf, af, loc_f, wf)
-        else:
-            rd = cache_lib.lookup(d, k, now, ttl_d, backend=backend,
-                                  buckets=loc_d)
-            rf = cache_lib.lookup(f, k, now, ttl_f, backend=backend,
-                                  buckets=loc_f)
-        return (_combine_probe(rd, own_d, g_d),
-                _combine_probe(rf, own_f, g_f))
+                ((hd, vd, ad, wd),
+                 (hf, vf, af, wf)) = probe_kernels.cache_probe_dual(
+                    d.key_hi, d.key_lo, d.write_ts, d.values,
+                    f.key_hi, f.key_lo, f.write_ts, f.values,
+                    k.hi, k.lo, loc_d, loc_f, now, ttl_d, ttl_f)
+                rd = cache_lib.LookupResult(hd, vd, ad, loc_d, wd)
+                rf = cache_lib.LookupResult(hf, vf, af, loc_f, wf)
+            else:
+                rd = cache_lib.lookup(d, k, now, ttl_d, backend=backend,
+                                      buckets=loc_d)
+                rf = cache_lib.lookup(f, k, now, ttl_f, backend=backend,
+                                      buckets=loc_f)
+            return (_combine_probe(rd, own_d, g_d),
+                    _combine_probe(rf, own_f, g_f))
 
-    sp = P(SHARD_AXIS)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(sp, sp, P(), P(), P(), P()),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )(direct, failover, keys, jnp.int32(now_ms),
-      jnp.asarray(direct_ttl_ms, jnp.int32),
-      jnp.asarray(failover_ttl_ms, jnp.int32))
+        sp = P(SHARD_AXIS)
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(sp, sp, P(), P(), P(), P()),
+            out_specs=(P(), P()),
+            check_vma=False,
+        )(direct, failover, keys, jnp.int32(now_ms),
+          jnp.asarray(direct_ttl_ms, jnp.int32),
+          jnp.asarray(failover_ttl_ms, jnp.int32))
 
 
 def sharded_lookup_dual_multi(mesh: Mesh, direct, failover, policy, slots,
@@ -273,53 +275,55 @@ def sharded_lookup_dual_multi(mesh: Mesh, direct, failover, policy, slots,
     Pooled bucket ids are computed replicated (they are a pure function of
     slot/key/policy), routed per shard, and probed against each shard's
     local flat view; the combine is the same per-cache one-hot psum."""
-    n_shards = mesh.shape[SHARD_AXIS]
-    nb_d, nb_f = direct.n_buckets, failover.n_buckets
-    nbl_d = cache_lib.shard_local_buckets(nb_d, n_shards)
-    nbl_f = cache_lib.shard_local_buckets(nb_f, n_shards)
-    slots = jnp.asarray(slots, jnp.int32)
-    b_d, b_f = cache_lib._pooled_bucket_pair(direct, failover, policy,
-                                             slots, keys)
-    ttl_d = policy.ttl_ms[slots]
-    ttl_f = policy.failover_ttl_ms[slots]
+    with jax.named_scope(trace.PROBE):
+        n_shards = mesh.shape[SHARD_AXIS]
+        nb_d, nb_f = direct.n_buckets, failover.n_buckets
+        nbl_d = cache_lib.shard_local_buckets(nb_d, n_shards)
+        nbl_f = cache_lib.shard_local_buckets(nb_f, n_shards)
+        slots = jnp.asarray(slots, jnp.int32)
+        b_d, b_f = cache_lib._pooled_bucket_pair(direct, failover, policy,
+                                                 slots, keys)
+        ttl_d = policy.ttl_ms[slots]
+        ttl_f = policy.failover_ttl_ms[slots]
 
-    def body(d, f, sl, k, g_d, g_f, td, tf, table, now):
-        shard = _shard_index()
-        own_d, loc_d = cache_lib.route_buckets(g_d, shard, nb_d, nbl_d)
-        own_f, loc_f = cache_lib.route_buckets(g_f, shard, nb_f, nbl_f)
-        fd, ff = d.flat(), f.flat()
-        if backend == "pallas":
-            from repro.kernels import cache_probe as probe_kernels
+        def body(d, f, sl, k, g_d, g_f, td, tf, table, now):
+            shard = _shard_index()
+            own_d, loc_d = cache_lib.route_buckets(g_d, shard, nb_d, nbl_d)
+            own_f, loc_f = cache_lib.route_buckets(g_f, shard, nb_f, nbl_f)
+            fd, ff = d.flat(), f.flat()
+            if backend == "pallas":
+                from repro.kernels import cache_probe as probe_kernels
 
-            ((hd, vd, ad, wd),
-             (hf, vf, af, wf)) = probe_kernels.cache_probe_dual_multi(
-                fd.key_hi, fd.key_lo, fd.write_ts, fd.values,
-                ff.key_hi, ff.key_lo, ff.write_ts, ff.values,
-                k.hi, k.lo, sl, loc_d, loc_f, table, now)
-            rd = cache_lib.LookupResult(hd, vd, ad, loc_d, wd)
-            rf = cache_lib.LookupResult(hf, vf, af, loc_f, wf)
-        else:
-            rd = cache_lib.lookup(fd, k, now, td, buckets=loc_d)
-            rf = cache_lib.lookup(ff, k, now, tf, buckets=loc_f)
-        return (_combine_probe(rd, own_d, g_d),
-                _combine_probe(rf, own_f, g_f))
+                ((hd, vd, ad, wd),
+                 (hf, vf, af, wf)) = probe_kernels.cache_probe_dual_multi(
+                    fd.key_hi, fd.key_lo, fd.write_ts, fd.values,
+                    ff.key_hi, ff.key_lo, ff.write_ts, ff.values,
+                    k.hi, k.lo, sl, loc_d, loc_f, table, now)
+                rd = cache_lib.LookupResult(hd, vd, ad, loc_d, wd)
+                rf = cache_lib.LookupResult(hf, vf, af, loc_f, wf)
+            else:
+                rd = cache_lib.lookup(fd, k, now, td, buckets=loc_d)
+                rf = cache_lib.lookup(ff, k, now, tf, buckets=loc_f)
+            return (_combine_probe(rd, own_d, g_d),
+                    _combine_probe(rf, own_f, g_f))
 
-    sp = P(None, SHARD_AXIS)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(sp, sp, P(), P(), P(), P(), P(), P(), P(), P()),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )(direct, failover, slots, keys, b_d, b_f, ttl_d, ttl_f,
-      policy.table(), jnp.int32(now_ms))
+        sp = P(None, SHARD_AXIS)
+        return jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(sp, sp, P(), P(), P(), P(), P(), P(), P(), P()),
+            out_specs=(P(), P()),
+            check_vma=False,
+        )(direct, failover, slots, keys, b_d, b_f, ttl_d, ttl_f,
+          policy.table(), jnp.int32(now_ms))
 
 
 def _touch_local(state, tb, bucket, way, nb_global, nb_local, shard):
     """One cache's deferred recency bumps, routed to this shard (global
     coordinates in the ring; -1 marks "no hit in that cache")."""
-    own, loc = cache_lib.route_buckets(bucket, shard, nb_global, nb_local)
-    live = wb_lib._touch_live(tb) & (bucket >= 0) & own
-    return cache_lib.touch(state, loc, way, tb.ts_ms, live=live)
+    with jax.named_scope(trace.TOUCH):
+        own, loc = cache_lib.route_buckets(bucket, shard, nb_global, nb_local)
+        live = wb_lib._touch_live(tb) & (bucket >= 0) & own
+        return cache_lib.touch(state, loc, way, tb.ts_ms, live=live)
 
 
 def sharded_flush(mesh: Mesh, buf, state, now_ms, ttl_ms, evict_lru=None,

@@ -364,13 +364,15 @@ def _make_probe_kernel(tq: int, n_tiers: int, multi: bool):
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("tile_q", "interpret"))
+@functools.partial(jax.jit, static_argnames=("name", "tile_q", "interpret"))
 def _probe(tiers, q_hi, q_lo, buckets, scalars, slots=None, policy=None, *,
-           tile_q: int, interpret: bool):
+           name: str, tile_q: int, interpret: bool):
     """One ``pallas_call`` probing every tier in ``tiers`` — a tuple of
     (key_hi, key_lo, write_ts, values) tables — for the same queries.
-    ``buckets`` holds each tier's (B,) bucket indices. Returns one
-    (hit, value, age, way) tuple per tier."""
+    ``buckets`` holds each tier's (B,) bucket indices. ``name`` is the
+    kernel's stable name: the compiled program's instruction and a
+    profile's op event carry it. Returns one (hit, value, age, way) tuple
+    per tier."""
     B = q_hi.shape[0]
     tq = tile_q
     pad = (-B) % tq
@@ -413,6 +415,7 @@ def _probe(tiers, q_hi, q_lo, buckets, scalars, slots=None, policy=None, *,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )(*prefetch, *rows, *views)
     return tuple((hit[0, :B].astype(bool), val.T[:B], age[0, :B], way[0, :B])
                  for hit, val, age, way in zip(*[iter(outs)] * 4))
@@ -430,7 +433,7 @@ def cache_probe_tiled(key_hi, key_lo, write_ts, values, q_hi, q_lo, buckets,
     LAUNCHES["tiled"] += 1
     out, = _probe(
         ((key_hi, key_lo, write_ts, values),), q_hi, q_lo, (buckets,),
-        jnp.asarray([now_ms, ttl_ms], jnp.int32),
+        jnp.asarray([now_ms, ttl_ms], jnp.int32), name="ercache_probe_tiled",
         tile_q=_pick_tile(q_hi.shape[0], tile_q),
         interpret=resolve_interpret(interpret))
     return out
@@ -462,7 +465,7 @@ def cache_probe_dual(d_key_hi, d_key_lo, d_write_ts, d_values,
          (f_key_hi, f_key_lo, f_write_ts, f_values)),
         q_hi, q_lo, (buckets_d, buckets_f),
         jnp.asarray([now_ms, ttl_direct_ms, ttl_failover_ms], jnp.int32),
-        tile_q=_pick_tile(q_hi.shape[0], tile_q),
+        name="ercache_probe_dual", tile_q=_pick_tile(q_hi.shape[0], tile_q),
         interpret=resolve_interpret(interpret))
 
 
@@ -487,7 +490,7 @@ def cache_probe_dual_multi(d_key_hi, d_key_lo, d_write_ts, d_values,
          (f_key_hi, f_key_lo, f_write_ts, f_values)),
         q_hi, q_lo, (buckets_d, buckets_f),
         jnp.asarray([now_ms], jnp.int32), slots,
-        jnp.asarray(policy, jnp.int32),
+        jnp.asarray(policy, jnp.int32), name="ercache_probe_dual_multi",
         tile_q=_pick_tile(q_hi.shape[0], tile_q),
         interpret=resolve_interpret(interpret))
 
@@ -548,6 +551,7 @@ def _cache_probe_perquery(key_hi, key_lo, write_ts, values, q_hi, q_lo,
             jax.ShapeDtypeStruct((B,), jnp.int32),
         ],
         interpret=interpret,
+        name="ercache_probe_perquery",
     )(buckets, scalars, key_hi, key_lo, write_ts, values, q_hi, q_lo)
     return hit.astype(bool), out, age
 

@@ -52,6 +52,7 @@ from repro.configs import get_config
 from repro.core import cache as cache_lib
 from repro.core import regional as rg_lib
 from repro.core import server as srv_lib
+from repro.core import trace
 from repro.core.config import (CacheConfig, MINUTE_MS, HOUR_MS,
                                multi_model_tier_configs)
 from repro.core.hashing import Key64
@@ -136,24 +137,30 @@ def _stage_chunk(uids, times_ms, features_of, lo: int, n_steps: int,
     — the scan driver's pre-staged stream. ``override_ids`` substitutes
     the user ids (the overload flash crowd) while keeping the clock.
     The failure mask is only staged when an injector rides along
-    (None otherwise — serve_many synthesizes its own zeros)."""
+    (None otherwise — serve_many synthesizes its own zeros).
+
+    Two host spans, one each per call: ``ercache/stage/rows`` (the per-step
+    hashing, features and failure masks) and ``ercache/stage/put`` (the
+    stacking and transfers to the device)."""
     khi, klo, feats, nows, fails = [], [], [], [], []
-    for s in range(n_steps):
-        a = lo + s * batch
-        ids = (uids[a:a + batch] if override_ids is None
-               else override_ids[s])
-        now = int(times_ms[a + batch - 1])
-        k = Key64.from_int(np.asarray(ids, np.int64))
-        khi.append(k.hi)
-        klo.append(k.lo)
-        feats.append(features_of(ids, now))
-        nows.append(now)
-        if injector is not None:
-            fails.append(injector.mask(batch, now))
-    keys = Key64(hi=jnp.stack(khi), lo=jnp.stack(klo))
-    feats = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *feats)
-    return (keys, feats, jnp.asarray(nows, jnp.int32),
-            jnp.asarray(np.stack(fails)) if fails else None)
+    with jax.profiler.TraceAnnotation(trace.STAGE_ROWS):
+        for s in range(n_steps):
+            a = lo + s * batch
+            ids = (uids[a:a + batch] if override_ids is None
+                   else override_ids[s])
+            now = int(times_ms[a + batch - 1])
+            k = Key64.from_int(np.asarray(ids, np.int64))
+            khi.append(k.hi)
+            klo.append(k.lo)
+            feats.append(features_of(ids, now))
+            nows.append(now)
+            if injector is not None:
+                fails.append(injector.mask(batch, now))
+    with jax.profiler.TraceAnnotation(trace.STAGE_PUT):
+        keys = Key64(hi=jnp.stack(khi), lo=jnp.stack(klo))
+        feats = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *feats)
+        return (keys, feats, jnp.asarray(nows, jnp.int32),
+                jnp.asarray(np.stack(fails)) if fails else None)
 
 
 def _chunks(n_batches: int, chunk_steps: int):

@@ -58,13 +58,19 @@ def _queries(sharding, n):
     return [_shape((BATCH,), jnp.int32, sharding)] * n
 
 
-def _assert_kernel_compiles(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel_compiles(name, fn, *args):
+    """The program holds the Pallas kernel, under its stable ``name`` (the
+    instruction a profile's op event is named after)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels
+    assert all(f"%{name}." in line.split("=")[0] for line in kernels)
 
 
 def test_tiled_probe_compiles_for_v5e(one_chip):
     _assert_kernel_compiles(
+        "ercache_probe_tiled",
         lambda khi, klo, ts, val, qhi, qlo, b: pk.cache_probe_tiled(
             khi, klo, ts, val, qhi, qlo, b, 1000, 500, interpret=False),
         *_tier(one_chip), *_queries(one_chip, 3))
@@ -72,12 +78,14 @@ def test_tiled_probe_compiles_for_v5e(one_chip):
 
 def test_dual_probe_compiles_for_v5e(one_chip):
     _assert_kernel_compiles(
+        "ercache_probe_dual",
         lambda *a: pk.cache_probe_dual(*a, 1000, 500, 900, interpret=False),
         *_tier(one_chip), *_tier(one_chip), *_queries(one_chip, 4))
 
 
 def test_dual_probe_compiles_for_v5e_at_widest_tower(one_chip):
     _assert_kernel_compiles(
+        "ercache_probe_dual",
         lambda *a: pk.cache_probe_dual(*a, 1000, 500, 900, interpret=False),
         *_tier(one_chip, 256, N_BUCKETS // 4),
         *_tier(one_chip, 256, N_BUCKETS // 4), *_queries(one_chip, 4))
@@ -85,6 +93,7 @@ def test_dual_probe_compiles_for_v5e_at_widest_tower(one_chip):
 
 def test_dual_multi_probe_compiles_for_v5e(one_chip):
     _assert_kernel_compiles(
+        "ercache_probe_dual_multi",
         lambda *a: pk.cache_probe_dual_multi(*a, 1000, interpret=False),
         *_tier(one_chip), *_tier(one_chip), *_queries(one_chip, 5),
         _shape((8, 2), jnp.int32, one_chip))
