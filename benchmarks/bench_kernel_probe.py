@@ -48,7 +48,7 @@ def run(report):
     k = Key64.from_int(probe_ids)
     buckets = bucket_index(k, N_BUCKETS)
     buckets_f = bucket_index(k, failover.n_buckets)
-    args = (state.key_hi, state.key_lo, state.write_ts, state.values,
+    args = (state.key_hi, state.key_lo, state.write_ts, state.value_plane,
             k.hi, k.lo, buckets, 1000, TTL_MS)
 
     # parity gate first: tiled == core.cache.lookup, bit for bit
@@ -68,8 +68,8 @@ def run(report):
                              warmup=1, iters=3 if not quick else 2)
     us_dual = common.time_us(
         pk.cache_probe_dual, state.key_hi, state.key_lo, state.write_ts,
-        state.values, failover.key_hi, failover.key_lo, failover.write_ts,
-        failover.values, k.hi, k.lo, buckets, buckets_f, 1000, TTL_MS,
+        state.value_plane, failover.key_hi, failover.key_lo, failover.write_ts,
+        failover.value_plane, k.hi, k.lo, buckets, buckets_f, 1000, TTL_MS,
         10 * TTL_MS)
 
     speedup = us_perq / us_tiled

@@ -6,8 +6,12 @@ in device HBM as a pytree of arrays and every operation is a pure function
 suitable for jit / pjit:
 
   * ``n_buckets`` buckets × ``ways`` slots (memcache-slab-like set-associative
-    layout — this is what makes lookup a single contiguous (ways, dim) gather,
-    which the Pallas ``cache_probe`` kernel exploits).
+    layout: a lookup reads one bucket's ``ways`` metadata slots, which the
+    Pallas ``cache_probe`` kernel lands with one DMA per plane).
+  * The values are one flat plane of entry columns, ``(dim, n_buckets *
+    ways)``: slot ``(bucket, way)`` is column ``bucket * ways + way``
+    (DESIGN.md §2). That is the order the compiler's scatter writes in, so
+    an insert updates the plane in place and no step relayouts it.
   * TTL-based validity and TTL-based eviction (paper §3.3): a hit requires the
     key to match AND ``now - write_ts <= ttl``; inserts pick, within the
     bucket:  key-match > empty > expired > oldest.
@@ -39,12 +43,16 @@ TS_EMPTY = jnp.int32(INT32_MIN)
 
 
 class CacheState(NamedTuple):
-    """All arrays of one cache namespace. Shardable along axis 0 (buckets)."""
+    """All arrays of one cache namespace. Shardable along the bucket axis:
+    axis 0 of the metadata planes, the entry axis of the value plane."""
 
     key_hi: jnp.ndarray    # (n_buckets, ways) int32
     key_lo: jnp.ndarray    # (n_buckets, ways) int32
     write_ts: jnp.ndarray  # (n_buckets, ways) int32, ms
-    values: jnp.ndarray    # (n_buckets, ways, dim)
+    # (dim, n_buckets * ways): slot (bucket, way) is column bucket*ways+way.
+    # Inside the serve program only this flat plane is used; ``values`` is
+    # the (n_buckets, ways, dim) view for code outside it.
+    value_plane: jnp.ndarray
     # Last-access recency plane: max(read timestamps) per slot, bumped off
     # the critical path via the touch buffer (writebuf.TouchBuffer). Writes
     # reset it to the write ts; TS_EMPTY until then. Only the LRU-timestamp
@@ -61,7 +69,15 @@ class CacheState(NamedTuple):
 
     @property
     def dim(self) -> int:
-        return self.values.shape[-1]
+        return self.value_plane.shape[0]
+
+    @property
+    def values(self) -> jnp.ndarray:
+        """(n_buckets, ways, dim) view of the value plane: a transposing
+        copy, for code outside the serve program (tests, snapshots,
+        tools)."""
+        return self.value_plane.reshape(
+            self.dim, self.n_buckets, self.ways).transpose(1, 2, 0)
 
     @property
     def capacity(self) -> int:
@@ -94,9 +110,45 @@ def init_cache(n_buckets: int, ways: int, dim: int,
         key_hi=jnp.full(shape, EMPTY_HI, dtype=jnp.int32),
         key_lo=jnp.full(shape, EMPTY_LO, dtype=jnp.int32),
         write_ts=jnp.full(shape, TS_EMPTY, dtype=jnp.int32),
-        values=jnp.zeros(shape + (dim,), dtype=dtype),
+        value_plane=jnp.zeros((dim, n_buckets * ways), dtype=dtype),
         last_access_ts=jnp.full(shape, TS_EMPTY, dtype=jnp.int32),
     )
+
+
+class TableImage(NamedTuple):
+    """A table (or a stacked tier) with its values in the (buckets, ways,
+    dim) view — ``(M, buckets, ways, dim)`` for a stacked tier. What
+    snapshots store (ft/snapshot.py; the leaf names and shapes of the
+    on-disk format) and what code outside the serve program builds tables
+    from; :func:`table_image` and :func:`from_image` convert."""
+
+    key_hi: jnp.ndarray
+    key_lo: jnp.ndarray
+    write_ts: jnp.ndarray
+    values: jnp.ndarray
+    last_access_ts: jnp.ndarray
+
+
+def table_image(state) -> TableImage:
+    """A :class:`CacheState` or :class:`MultiCacheState` as its image."""
+    return TableImage(key_hi=state.key_hi, key_lo=state.key_lo,
+                      write_ts=state.write_ts, values=state.values,
+                      last_access_ts=state.last_access_ts)
+
+
+def from_image(image: TableImage):
+    """Inverse of :func:`table_image`: a :class:`CacheState` from a
+    single-table image, a :class:`MultiCacheState` from a stacked one."""
+    values = jnp.asarray(image.values)
+    if values.ndim == 4:
+        plane = values.transpose(3, 1, 0, 2).reshape(values.shape[-1], -1)
+        cls = MultiCacheState
+    else:
+        plane = values.reshape(-1, values.shape[-1]).T
+        cls = CacheState
+    return cls(key_hi=image.key_hi, key_lo=image.key_lo,
+               write_ts=image.write_ts, value_plane=plane,
+               last_access_ts=image.last_access_ts)
 
 
 def flat_entries(state: CacheState):
@@ -113,7 +165,7 @@ def flat_entries(state: CacheState):
     n = state.n_buckets * state.ways
     keys = Key64(hi=state.key_hi.reshape(n), lo=state.key_lo.reshape(n))
     live = ~((keys.hi == EMPTY_HI) & (keys.lo == EMPTY_LO))
-    return (keys, state.values.reshape(n, state.dim),
+    return (keys, state.value_plane.T,
             state.write_ts.reshape(n), state.last_access_ts.reshape(n),
             live)
 
@@ -184,26 +236,34 @@ def _probe(state: CacheState, keys: Key64, bucket=None):
     return bucket, match, empty, ts
 
 
+def _entry_values(plane, cols) -> jnp.ndarray:
+    """(B, dim) values of entry columns ``cols`` of a value plane."""
+    return jnp.take(plane, cols, axis=1, mode="clip").T
+
+
 def lookup(state: CacheState, keys: Key64, now_ms, ttl_ms,
-           backend: str = "jnp", buckets=None) -> LookupResult:
+           backend: str = "jnp", buckets=None,
+           value_buckets=None) -> LookupResult:
     """Batched TTL-validated lookup.
 
     ``backend="jnp"`` is the pure-jnp reference path (the bit-exact oracle);
     ``backend="pallas"`` dispatches the tiled ``cache_probe`` kernel
     (kernels/cache_probe.py) — tests assert the two agree bit-exactly.
     ``ttl_ms`` may be a scalar or a per-query (B,) vector (multi-model
-    policies); ``buckets`` optionally overrides the hash-derived index.
+    policies); ``buckets`` optionally overrides the hash-derived index and
+    ``value_buckets`` the bucket a query's values sit at in the value plane
+    (a stacked tier's pooled view, :meth:`MultiCacheState.value_buckets`).
     """
     if backend == "pallas":
         from repro.kernels import cache_probe as probe_kernels
 
-        if jnp.asarray(ttl_ms).ndim:
-            raise ValueError("per-query ttl_ms needs the multi-model "
-                             "kernel: use lookup_dual_multi")
+        if jnp.asarray(ttl_ms).ndim or value_buckets is not None:
+            raise ValueError("per-query ttl_ms or a stacked tier needs the "
+                             "multi-model kernel: use lookup_dual_multi")
         if buckets is None:
             buckets = bucket_index(keys, state.n_buckets)
         hit, vals, age, way = probe_kernels.cache_probe_tiled(
-            state.key_hi, state.key_lo, state.write_ts, state.values,
+            state.key_hi, state.key_lo, state.write_ts, state.value_plane,
             keys.hi, keys.lo, buckets, now_ms, ttl_ms)
         return LookupResult(hit=hit, values=vals, age_ms=age,
                             bucket=buckets, way=way)
@@ -217,15 +277,15 @@ def lookup(state: CacheState, keys: Key64, now_ms, ttl_ms,
     hit = jnp.any(valid, axis=-1)
     # At most one way can match a given key (insert overwrites matches), so
     # argmax of the bool picks the unique valid way when hit.
-    way = jnp.argmax(valid, axis=-1)
-    vals = state.values[bucket, way]
+    way = jnp.argmax(valid, axis=-1).astype(jnp.int32)
+    vb = bucket if value_buckets is None else value_buckets
+    vals = _entry_values(state.value_plane, vb * state.ways + way)
     vals = jnp.where(hit[:, None], vals, jnp.zeros_like(vals))
     # erlint: allow[ER004] — miss lanes (incl. TS_EMPTY wrap) forced to -1
     age = jnp.where(hit, now_ms - ts[jnp.arange(keys.hi.shape[0]), way],
                     jnp.int32(-1))
     return LookupResult(hit=hit, values=vals, age_ms=age, bucket=bucket,
-                        way=jnp.where(hit, way.astype(jnp.int32),
-                                      jnp.int32(-1)))
+                        way=jnp.where(hit, way, jnp.int32(-1)))
 
 
 def lookup_dual(direct: CacheState, failover: CacheState, keys: Key64,
@@ -245,9 +305,10 @@ def lookup_dual(direct: CacheState, failover: CacheState, keys: Key64,
             b_f = bucket_index(keys, failover.n_buckets)
             ((hd, vd, ad, wd),
              (hf, vf, af, wf)) = probe_kernels.cache_probe_dual(
-                direct.key_hi, direct.key_lo, direct.write_ts, direct.values,
-                failover.key_hi, failover.key_lo, failover.write_ts,
-                failover.values, keys.hi, keys.lo, b_d, b_f,
+                direct.key_hi, direct.key_lo, direct.write_ts,
+                direct.value_plane, failover.key_hi, failover.key_lo,
+                failover.write_ts, failover.value_plane, keys.hi, keys.lo,
+                b_d, b_f,
                 now_ms, direct_ttl_ms, failover_ttl_ms)
             return (LookupResult(hit=hd, values=vd, age_ms=ad, bucket=b_d,
                                  way=wd),
@@ -483,19 +544,27 @@ def plan_insert(state: CacheState, keys: Key64, now_ms, ttl_ms,
 
 
 def _scatter_insert(state: CacheState, keys: Key64, values, ts_vec,
-                    winner, bucket, way) -> CacheState:
+                    winner, bucket, way, value_buckets=None) -> CacheState:
     """Apply a resolved insert plan. mode='drop': losers get an
-    out-of-range bucket. A write resets the slot's last_access_ts to the
+    out-of-range bucket (entry column, for the value plane). The value
+    plane takes ONE scatter of the winners' columns at ``bucket * ways +
+    way`` (``value_buckets`` in place of ``bucket`` on a stacked tier's
+    pooled view): the plane is stored in the scatter's own order, so it is
+    written in place. A write resets the slot's last_access_ts to the
     write timestamp — stale touch coordinates from a previous occupant
     must never boost the new entry's recency."""
     with jax.named_scope(trace.SCATTER):
         b_w = jnp.where(winner, bucket, jnp.int32(state.n_buckets))
+        vb = bucket if value_buckets is None else value_buckets
+        plane = state.value_plane
+        cols = jnp.where(winner, vb * state.ways + way,
+                         jnp.int32(plane.shape[1]))
         return CacheState(
             key_hi=state.key_hi.at[b_w, way].set(keys.hi, mode="drop"),
             key_lo=state.key_lo.at[b_w, way].set(keys.lo, mode="drop"),
             write_ts=state.write_ts.at[b_w, way].set(ts_vec, mode="drop"),
-            values=state.values.at[b_w, way].set(
-                values.astype(state.values.dtype), mode="drop"),
+            value_plane=plane.at[:, cols].set(values.astype(plane.dtype).T,
+                                              mode="drop"),
             last_access_ts=state.last_access_ts.at[b_w, way].set(ts_vec,
                                                                  mode="drop"),
         )
@@ -513,7 +582,7 @@ def insert(state: CacheState, keys: Key64, values: jnp.ndarray,
            write_mask: Optional[jnp.ndarray] = None,
            ts_ms: Optional[jnp.ndarray] = None,
            evict_lru=None, buckets=None,
-           dedupe_salt=None) -> CacheState:
+           dedupe_salt=None, value_buckets=None) -> CacheState:
     """Batched insert/overwrite with sequential-write emulation (see
     ``plan_insert``).
 
@@ -523,7 +592,8 @@ def insert(state: CacheState, keys: Key64, values: jnp.ndarray,
       computed at t but flushed at t+δ ages from t, not t+δ — async writes
       (paper §3.5) move work off the critical path without faking freshness.
     * ``evict_lru`` / ``buckets`` / ``dedupe_salt``: multi-model plan knobs,
-      forwarded to :func:`plan_insert`.
+      forwarded to :func:`plan_insert`; ``value_buckets`` as in
+      :func:`lookup`.
     """
     winner, bucket, way = plan_insert(state, keys, now_ms, ttl_ms,
                                       write_mask, evict_lru=evict_lru,
@@ -531,7 +601,7 @@ def insert(state: CacheState, keys: Key64, values: jnp.ndarray,
                                       dedupe_salt=dedupe_salt)
     return _scatter_insert(state, keys, values,
                            _ts_vector(values, now_ms, ts_ms),
-                           winner, bucket, way)
+                           winner, bucket, way, value_buckets)
 
 
 def touch(state: CacheState, bucket, way, ts_ms,
@@ -566,7 +636,8 @@ def insert_dual(direct: CacheState, failover: CacheState, keys: Key64,
                 write_mask: Optional[jnp.ndarray] = None,
                 ts_ms: Optional[jnp.ndarray] = None,
                 evict_lru=None, buckets_d=None, buckets_f=None,
-                dedupe_salt=None):
+                dedupe_salt=None, value_buckets_d=None,
+                value_buckets_f=None):
     """Insert the same records into BOTH caches with ONE shared plan.
 
     The batch dedupe (the plan's lexsort) depends only on the keys (plus
@@ -579,7 +650,8 @@ def insert_dual(direct: CacheState, failover: CacheState, keys: Key64,
     Results are bit-identical to two independent :func:`insert` calls.
 
     TTLs may be per-query vectors and ``evict_lru`` switches the eviction
-    policy per query — the multi-model flush path (DESIGN.md §5).
+    policy per query — the multi-model flush path (DESIGN.md §5), which
+    also passes each tier's ``value_buckets`` (see :func:`lookup`).
 
     Returns (new_direct, new_failover).
     """
@@ -602,7 +674,7 @@ def insert_dual(direct: CacheState, failover: CacheState, keys: Key64,
         win_d = _resolve_collisions(winner, b_d, way_d, direct.n_buckets,
                                     direct.ways)
     new_direct = _scatter_insert(direct, keys, values, ts_vec,
-                                 win_d, b_d, way_d)
+                                 win_d, b_d, way_d, value_buckets_d)
 
     with jax.named_scope(trace.PLAN):
         # Probe results must come from the failover's own contents; only the
@@ -623,7 +695,7 @@ def insert_dual(direct: CacheState, failover: CacheState, keys: Key64,
         win_f = _resolve_collisions(winner, b_f, way_f, failover.n_buckets,
                                     failover.ways)
     new_failover = _scatter_insert(failover, keys, values, ts_vec,
-                                   win_f, b_f, way_f)
+                                   win_f, b_f, way_f, value_buckets_f)
     return new_direct, new_failover
 
 
@@ -716,12 +788,19 @@ class MultiCacheState(NamedTuple):
     ``n_buckets[m]`` rows of its slab (its bucket mask is narrower) — the
     tail rows simply stay empty. Ways and dim are uniform across the tier
     (heterogeneous ``ways`` are normalized up to the tier maximum).
+
+    The value plane is one flat ``(dim, n_buckets * M * ways)`` plane with
+    the models interleaved within each bucket: slot ``(model, bucket,
+    way)`` is column ``(bucket * M + model) * ways + way``. A contiguous
+    split of its entry axis is then a split of every model's bucket range,
+    the same one bucket sharding makes of the metadata planes (DESIGN.md
+    §11).
     """
 
     key_hi: jnp.ndarray    # (M, n_buckets, ways) int32
     key_lo: jnp.ndarray    # (M, n_buckets, ways) int32
     write_ts: jnp.ndarray  # (M, n_buckets, ways) int32, ms
-    values: jnp.ndarray    # (M, n_buckets, ways, dim)
+    value_plane: jnp.ndarray  # (dim, n_buckets * M * ways)
     last_access_ts: jnp.ndarray  # (M, n_buckets, ways) int32, ms
 
     @property
@@ -739,17 +818,33 @@ class MultiCacheState(NamedTuple):
 
     @property
     def dim(self) -> int:
-        return self.values.shape[-1]
+        return self.value_plane.shape[0]
+
+    @property
+    def values(self) -> jnp.ndarray:
+        """(M, n_buckets, ways, dim) view of the value plane (a copy, for
+        code outside the serve program)."""
+        return self.value_plane.reshape(
+            self.dim, self.n_buckets, self.n_models, self.ways
+        ).transpose(2, 1, 3, 0)
+
+    def value_buckets(self, pooled) -> jnp.ndarray:
+        """Pooled bucket ids (``slot * Nb + bucket``, the metadata's flat
+        view) → the bucket of the value plane their entries sit in,
+        ``bucket * M + slot`` — what :func:`lookup` and :func:`insert` take
+        as ``value_buckets`` on the :meth:`flat` view."""
+        return pooled_value_buckets(pooled, self.n_models, self.n_buckets)
 
     def flat(self) -> CacheState:
         """The (M*Nb, W) pooled view the shared probe/insert math runs on.
-        A reshape of contiguous arrays — no copy under XLA."""
+        A reshape of contiguous arrays — no copy under XLA. Its value
+        plane is the stacked one: address it with :meth:`value_buckets`."""
         M, Nb, W = self.key_hi.shape
         return CacheState(
             key_hi=self.key_hi.reshape(M * Nb, W),
             key_lo=self.key_lo.reshape(M * Nb, W),
             write_ts=self.write_ts.reshape(M * Nb, W),
-            values=self.values.reshape(M * Nb, W, self.values.shape[-1]),
+            value_plane=self.value_plane,
             last_access_ts=self.last_access_ts.reshape(M * Nb, W),
         )
 
@@ -760,22 +855,24 @@ class MultiCacheState(NamedTuple):
             key_hi=flat.key_hi.reshape(M, Nb, W),
             key_lo=flat.key_lo.reshape(M, Nb, W),
             write_ts=flat.write_ts.reshape(M, Nb, W),
-            values=flat.values.reshape(M, Nb, W, self.values.shape[-1]),
+            value_plane=flat.value_plane,
             last_access_ts=flat.last_access_ts.reshape(M, Nb, W),
         )
 
     def model_view(self, slot: int, n_buckets: Optional[int] = None
                    ) -> CacheState:
         """Model ``slot``'s slab as a standalone CacheState (the per-model
-        jnp oracle's operand). ``n_buckets`` trims to the model's own
-        configured capacity so ``bucket_index`` reproduces the pooled
-        mapping."""
+        jnp oracle's operand; its value plane is a copy). ``n_buckets``
+        trims to the model's own configured capacity so ``bucket_index``
+        reproduces the pooled mapping."""
         nb = self.n_buckets if n_buckets is None else n_buckets
+        plane = self.value_plane.reshape(self.dim, self.n_buckets,
+                                         self.n_models, self.ways)
         return CacheState(
             key_hi=self.key_hi[slot, :nb],
             key_lo=self.key_lo[slot, :nb],
             write_ts=self.write_ts[slot, :nb],
-            values=self.values[slot, :nb],
+            value_plane=plane[:, :nb, slot].reshape(self.dim, nb * self.ways),
             last_access_ts=self.last_access_ts[slot, :nb],
         )
 
@@ -793,9 +890,18 @@ def init_multi_cache(n_buckets: Sequence[int], ways: int, dim: int,
         key_hi=jnp.full(shape, EMPTY_HI, dtype=jnp.int32),
         key_lo=jnp.full(shape, EMPTY_LO, dtype=jnp.int32),
         write_ts=jnp.full(shape, TS_EMPTY, dtype=jnp.int32),
-        values=jnp.zeros(shape + (dim,), dtype=dtype),
+        value_plane=jnp.zeros((dim, M * nb_max * ways), dtype=dtype),
         last_access_ts=jnp.full(shape, TS_EMPTY, dtype=jnp.int32),
     )
+
+
+def pooled_value_buckets(pooled, n_models: int, nb_stack: int
+                         ) -> jnp.ndarray:
+    """See :meth:`MultiCacheState.value_buckets`; ``nb_stack`` is the
+    stack's buckets per model (a shard's local count inside a
+    bucket-sharded computation)."""
+    slot = pooled // nb_stack
+    return (pooled - slot * nb_stack) * n_models + slot
 
 
 def pooled_buckets(slots, keys: Key64, bucket_mask, nb_stack: int
@@ -852,8 +958,8 @@ def lookup_dual_multi(direct: MultiCacheState, failover: MultiCacheState,
             fd, ff = direct.flat(), failover.flat()
             ((hd, vd, ad, wd),
              (hf, vf, af, wf)) = probe_kernels.cache_probe_dual_multi(
-                fd.key_hi, fd.key_lo, fd.write_ts, fd.values,
-                ff.key_hi, ff.key_lo, ff.write_ts, ff.values,
+                fd.key_hi, fd.key_lo, fd.write_ts, fd.value_plane,
+                ff.key_hi, ff.key_lo, ff.write_ts, ff.value_plane,
                 keys.hi, keys.lo, slots, b_d, b_f, policy.table(), now_ms)
             return (LookupResult(hit=hd, values=vd, age_ms=ad, bucket=b_d,
                                  way=wd),
@@ -862,9 +968,10 @@ def lookup_dual_multi(direct: MultiCacheState, failover: MultiCacheState,
         if backend != "jnp":
             raise ValueError(f"unknown cache backend: {backend!r}")
         return (lookup(direct.flat(), keys, now_ms, policy.ttl_ms[slots],
-                       buckets=b_d),
+                       buckets=b_d, value_buckets=direct.value_buckets(b_d)),
                 lookup(failover.flat(), keys, now_ms,
-                       policy.failover_ttl_ms[slots], buckets=b_f))
+                       policy.failover_ttl_ms[slots], buckets=b_f,
+                       value_buckets=failover.value_buckets(b_f)))
 
 
 def insert_dual_multi(direct: MultiCacheState, failover: MultiCacheState,
@@ -890,5 +997,7 @@ def insert_dual_multi(direct: MultiCacheState, failover: MultiCacheState,
         policy.ttl_ms[slots], policy.failover_ttl_ms[slots],
         write_mask=write_mask, ts_ms=ts_ms,
         evict_lru=policy.evict_lru[slots],
-        buckets_d=b_d, buckets_f=b_f, dedupe_salt=slots)
+        buckets_d=b_d, buckets_f=b_f, dedupe_salt=slots,
+        value_buckets_d=direct.value_buckets(b_d),
+        value_buckets_f=failover.value_buckets(b_f))
     return direct.with_flat(new_d), failover.with_flat(new_f)

@@ -79,12 +79,12 @@ def insert_group(spec: GroupSpec, state: GroupedCacheState, keys: Key64,
     for i, m in enumerate(spec.members):
         v = member_values.get(m.name)
         if v is None:
-            rows.append(jnp.zeros((B, m.dim), state.base.values.dtype))
+            rows.append(jnp.zeros((B, m.dim), state.base.value_plane.dtype))
             continue
         ok = (member_mask or {}).get(m.name)
         if ok is None:
             ok = jnp.ones((B,), bool)
-        rows.append(jnp.where(ok[:, None], v, 0).astype(state.base.values.dtype))
+        rows.append(jnp.where(ok[:, None], v, 0).astype(state.base.value_plane.dtype))
         bits = bits | jnp.where(ok, jnp.int32(1 << i), jnp.int32(0))
     group_row = jnp.concatenate(rows, axis=-1)
 

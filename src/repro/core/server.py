@@ -1098,8 +1098,11 @@ def cache_image(state):
     bucket. Works on :class:`ServerState` and :class:`MultiServerState`
     alike. The write/touch rings are deliberately NOT part of the image:
     the snapshot path drains them into the tables first (``flush``), so
-    the image is a pure cache state with no half-applied async work."""
-    return {"direct": state.direct, "failover": state.failover,
+    the image is a pure cache state with no half-applied async work. The
+    tables go in their (buckets, ways, dim) view (``cache.TableImage``),
+    the snapshot format."""
+    return {"direct": cache_lib.table_image(state.direct),
+            "failover": cache_lib.table_image(state.failover),
             "budget": state.budget}
 
 
@@ -1107,8 +1110,8 @@ def with_cache_image(state, image):
     """Graft a restored durable image onto a freshly initialized state of
     the SAME shape; the buffers keep their cold (empty) allocation — the
     snapshot drained them, so empty rings are the faithful restore."""
-    return state._replace(direct=image["direct"],
-                          failover=image["failover"],
+    return state._replace(direct=cache_lib.from_image(image["direct"]),
+                          failover=cache_lib.from_image(image["failover"]),
                           budget=image["budget"])
 
 

@@ -183,13 +183,17 @@ from repro.core import writebuf as wb_lib
 SHARD_AXIS = "shard"
 
 
-def cache_pspec(state) -> P:
-    """The bucket-axis PartitionSpec for a (Multi)CacheState — a tree-prefix
-    spec (one P covers every leaf: bucket is axis 0 of a CacheState leaf,
-    axis 1 behind the model axis of a MultiCacheState leaf)."""
-    if isinstance(state, cache_lib.MultiCacheState):
-        return P(None, SHARD_AXIS)
-    return P(SHARD_AXIS)
+def cache_pspec(state):
+    """The bucket-axis PartitionSpecs of a (Multi)CacheState, one per
+    field: bucket is axis 0 of a CacheState metadata plane, axis 1 behind
+    the model axis of a MultiCacheState one, and the value plane splits
+    along its entry axis (axis 1), where every shard's buckets — of every
+    model, in a stacked tier — are contiguous."""
+    meta = P(None, SHARD_AXIS) if isinstance(
+        state, cache_lib.MultiCacheState) else P(SHARD_AXIS)
+    return type(state)(key_hi=meta, key_lo=meta, write_ts=meta,
+                       value_plane=P(None, SHARD_AXIS),
+                       last_access_ts=meta)
 
 
 def _shard_index():
@@ -244,8 +248,8 @@ def sharded_lookup_dual(mesh: Mesh, direct, failover, keys, now_ms,
 
                 ((hd, vd, ad, wd),
                  (hf, vf, af, wf)) = probe_kernels.cache_probe_dual(
-                    d.key_hi, d.key_lo, d.write_ts, d.values,
-                    f.key_hi, f.key_lo, f.write_ts, f.values,
+                    d.key_hi, d.key_lo, d.write_ts, d.value_plane,
+                    f.key_hi, f.key_lo, f.write_ts, f.value_plane,
                     k.hi, k.lo, loc_d, loc_f, now, ttl_d, ttl_f)
                 rd = cache_lib.LookupResult(hd, vd, ad, loc_d, wd)
                 rf = cache_lib.LookupResult(hf, vf, af, loc_f, wf)
@@ -257,10 +261,10 @@ def sharded_lookup_dual(mesh: Mesh, direct, failover, keys, now_ms,
             return (_combine_probe(rd, own_d, g_d),
                     _combine_probe(rf, own_f, g_f))
 
-        sp = P(SHARD_AXIS)
         return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(sp, sp, P(), P(), P(), P()),
+            in_specs=(cache_pspec(direct), cache_pspec(failover), P(), P(),
+                      P(), P()),
             out_specs=(P(), P()),
             check_vma=False,
         )(direct, failover, keys, jnp.int32(now_ms),
@@ -296,21 +300,23 @@ def sharded_lookup_dual_multi(mesh: Mesh, direct, failover, policy, slots,
 
                 ((hd, vd, ad, wd),
                  (hf, vf, af, wf)) = probe_kernels.cache_probe_dual_multi(
-                    fd.key_hi, fd.key_lo, fd.write_ts, fd.values,
-                    ff.key_hi, ff.key_lo, ff.write_ts, ff.values,
+                    fd.key_hi, fd.key_lo, fd.write_ts, fd.value_plane,
+                    ff.key_hi, ff.key_lo, ff.write_ts, ff.value_plane,
                     k.hi, k.lo, sl, loc_d, loc_f, table, now)
                 rd = cache_lib.LookupResult(hd, vd, ad, loc_d, wd)
                 rf = cache_lib.LookupResult(hf, vf, af, loc_f, wf)
             else:
-                rd = cache_lib.lookup(fd, k, now, td, buckets=loc_d)
-                rf = cache_lib.lookup(ff, k, now, tf, buckets=loc_f)
+                rd = cache_lib.lookup(fd, k, now, td, buckets=loc_d,
+                                      value_buckets=d.value_buckets(loc_d))
+                rf = cache_lib.lookup(ff, k, now, tf, buckets=loc_f,
+                                      value_buckets=f.value_buckets(loc_f))
             return (_combine_probe(rd, own_d, g_d),
                     _combine_probe(rf, own_f, g_f))
 
-        sp = P(None, SHARD_AXIS)
         return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(sp, sp, P(), P(), P(), P(), P(), P(), P(), P()),
+            in_specs=(cache_pspec(direct), cache_pspec(failover), P(), P(),
+                      P(), P(), P(), P(), P(), P()),
             out_specs=(P(), P()),
             check_vma=False,
         )(direct, failover, slots, keys, b_d, b_f, ttl_d, ttl_f,
@@ -344,10 +350,11 @@ def sharded_flush(mesh: Mesh, buf, state, now_ms, ttl_ms, evict_lru=None,
                                 write_mask=live & own, ts_ms=ts,
                                 evict_lru=evict_lru, buckets=loc)
 
+    sp = cache_pspec(state)
     new_state = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(), P(), P()),
-        out_specs=P(SHARD_AXIS),
+        in_specs=(sp, P(), P(), P()),
+        out_specs=sp,
         check_vma=False,
     )(state, buf, touchbuf, jnp.int32(now_ms))
     return (new_state, buf._replace(count=jnp.int32(0)),
@@ -390,11 +397,11 @@ def sharded_flush_dual(mesh: Mesh, buf, direct, failover, now_ms,
                              evict_lru=evict_lru, buckets=loc_f)
         return d, f
 
-    sp = P(SHARD_AXIS)
+    sp_d, sp_f = cache_pspec(direct), cache_pspec(failover)
     new_d, new_f = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(sp, sp, P(), P(), P()),
-        out_specs=(sp, sp),
+        in_specs=(sp_d, sp_f, P(), P(), P()),
+        out_specs=(sp_d, sp_f),
         check_vma=False,
     )(direct, failover, buf, touchbuf, jnp.int32(now_ms))
     return (new_d, new_f, buf._replace(count=jnp.int32(0)),
@@ -431,18 +438,20 @@ def sharded_flush_dual_multi(mesh: Mesh, buf, direct, failover, policy,
         fd = cache_lib.insert(fd, keys, values, now, ttl_d[slots],
                               write_mask=live & own_d, ts_ms=ts,
                               evict_lru=lru[slots], buckets=loc_d,
-                              dedupe_salt=slots)
+                              dedupe_salt=slots,
+                              value_buckets=d.value_buckets(loc_d))
         ff = cache_lib.insert(ff, keys, values, now, ttl_f[slots],
                               write_mask=live & own_f, ts_ms=ts,
                               evict_lru=lru[slots], buckets=loc_f,
-                              dedupe_salt=slots)
+                              dedupe_salt=slots,
+                              value_buckets=f.value_buckets(loc_f))
         return d.with_flat(fd), f.with_flat(ff)
 
-    sp = P(None, SHARD_AXIS)
+    sp_d, sp_f = cache_pspec(direct), cache_pspec(failover)
     new_d, new_f = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(sp, sp, P(), P(), P(), P(), P(), P(), P(), P()),
-        out_specs=(sp, sp),
+        in_specs=(sp_d, sp_f, P(), P(), P(), P(), P(), P(), P(), P()),
+        out_specs=(sp_d, sp_f),
         check_vma=False,
     )(direct, failover, buf, touchbuf, policy.bucket_mask_d,
       policy.bucket_mask_f, policy.ttl_ms, policy.failover_ttl_ms,

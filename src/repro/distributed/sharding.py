@@ -189,7 +189,8 @@ def validate_cache_sharding(mesh: Mesh, n_buckets_list) -> int:
 
 
 def _server_state_shardings(state, mesh: Mesh):
-    """Cache tables sharded along their bucket axis, everything else
+    """Cache tables sharded along their bucket axis (the value planes
+    along their entry axis), everything else
     (rings, budget) replicated — as a tree of NamedShardings."""
     from repro.distributed import collectives as coll
 
@@ -200,9 +201,14 @@ def _server_state_shardings(state, mesh: Mesh):
         sh = NamedSharding(mesh, pspec)
         return jax.tree_util.tree_map(lambda _: sh, tree)
 
+    def tables(tier):
+        return jax.tree_util.tree_map(lambda p: NamedSharding(mesh, p),
+                                      coll.cache_pspec(tier),
+                                      is_leaf=lambda x: isinstance(x, P))
+
     return state._replace(
-        direct=spec(state.direct, coll.cache_pspec(state.direct)),
-        failover=spec(state.failover, coll.cache_pspec(state.failover)),
+        direct=tables(state.direct),
+        failover=tables(state.failover),
         writebuf=spec(state.writebuf, P()),
         touchbuf=spec(state.touchbuf, P()),
         budget=spec(state.budget, P()),

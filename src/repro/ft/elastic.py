@@ -201,6 +201,7 @@ def rehash_multi_cache(old: C.MultiCacheState,
     """
     assert old.n_models == new.n_models, (old.n_models, new.n_models)
     counts: List[int] = []
+    image = C.table_image(new)
     for m in range(new.n_models):
         old_v = old.model_view(m, int(old_n_buckets[m]))
         nb = int(new_n_buckets[m])
@@ -208,12 +209,8 @@ def rehash_multi_cache(old: C.MultiCacheState,
             old_v, new.model_view(m, nb), now_ms, int(ttl_ms[m]),
             evict_lru=None if evict_lru is None else bool(evict_lru[m]),
             chunk=chunk)
-        new = C.MultiCacheState(
-            key_hi=new.key_hi.at[m, :nb].set(out.key_hi),
-            key_lo=new.key_lo.at[m, :nb].set(out.key_lo),
-            write_ts=new.write_ts.at[m, :nb].set(out.write_ts),
-            values=new.values.at[m, :nb].set(out.values),
-            last_access_ts=new.last_access_ts.at[m, :nb].set(
-                out.last_access_ts))
+        image = C.TableImage(*(
+            whole.at[m, :nb].set(part) for whole, part in
+            zip(image, C.table_image(out))))
         counts.append(cnt)
-    return new, counts
+    return C.from_image(image), counts

@@ -105,7 +105,7 @@ def snapshot_server(directory: str, step: int, server, state, now_ms: int,
         "kind": kind,
         "now_ms": int(now_ms),
         "value_dim": int(tier.direct.dim),
-        "dtype": str(tier.direct.values.dtype),
+        "dtype": str(tier.direct.value_plane.dtype),
         "shapes": _shape_meta(server, state),
         "counters": None if counters is None else counters.as_dict(),
     }
@@ -130,7 +130,7 @@ def _as_stack(single: cache_lib.CacheState) -> cache_lib.MultiCacheState:
     conversion on restore)."""
     return cache_lib.MultiCacheState(
         key_hi=single.key_hi[None], key_lo=single.key_lo[None],
-        write_ts=single.write_ts[None], values=single.values[None],
+        write_ts=single.write_ts[None], value_plane=single.value_plane,
         last_access_ts=single.last_access_ts[None])
 
 
@@ -224,7 +224,8 @@ def restore_server(directory: str, server, now_ms: int,
             old_f = cache_lib.init_multi_cache(
                 shapes["failover_nb"], shapes["failover_ways"], dim, dtype)
             image = ckpt.restore(directory, step, {
-                "direct": old_d, "failover": old_f,
+                "direct": cache_lib.table_image(old_d),
+                "failover": cache_lib.table_image(old_f),
                 "budget": InferBudget(tokens=jnp.zeros(
                     (int(shapes["n_models"]),), jnp.float32)),
                 "home": jnp.zeros((int(shapes["n_users"]),), jnp.int32)})
@@ -254,8 +255,11 @@ def restore_server(directory: str, server, now_ms: int,
         else:
             return cold_result(f"step {step}: unknown kind {kind!r}", step)
         image = ckpt.restore(directory, step, {
-            "direct": old_d, "failover": old_f,
+            "direct": cache_lib.table_image(old_d),
+            "failover": cache_lib.table_image(old_f),
             "budget": InferBudget(tokens=jnp.zeros((n_old,), jnp.float32))})
+        old_d = cache_lib.from_image(image["direct"])
+        old_f = cache_lib.from_image(image["failover"])
         counters = (ServingCounters.from_dict(meta["counters"])
                     if meta.get("counters") else ServingCounters())
 
@@ -268,8 +272,8 @@ def restore_server(directory: str, server, now_ms: int,
 
         same_kind = (kind == "multi") == multi
         if same_kind and shapes == _shape_meta(server, cold):
-            state = server_lib.with_cache_image(
-                cold, dict(image, budget=budget))
+            state = cold._replace(direct=old_d, failover=old_f,
+                                  budget=budget)
             return RestoreResult(state=place(state), counters=counters,
                                  mode="bitexact", step=step,
                                  detail=f"loaded step {step} in place")
@@ -281,15 +285,14 @@ def restore_server(directory: str, server, now_ms: int,
                     return cold_result(
                         f"step {step}: single-model snapshot into a "
                         f"{server.n_models}-model tier", step)
-                old_dm, old_fm = _as_stack(image["direct"]), \
-                    _as_stack(image["failover"])
+                old_dm, old_fm = _as_stack(old_d), _as_stack(old_f)
                 nb_d, nb_f = [shapes["direct_nb"]], [shapes["failover_nb"]]
             else:
                 if n_old != server.n_models:
                     return cold_result(
                         f"step {step}: snapshot has {n_old} models, "
                         f"target has {server.n_models}", step)
-                old_dm, old_fm = image["direct"], image["failover"]
+                old_dm, old_fm = old_d, old_f
                 nb_d, nb_f = shapes["direct_nb"], shapes["failover_nb"]
             cfgs = list(server.cfgs)
             lru = [c.eviction == "lru" for c in cfgs]
@@ -308,12 +311,10 @@ def restore_server(directory: str, server, now_ms: int,
                     return cold_result(
                         f"step {step}: {n_old}-model snapshot into a "
                         "single-model server", step)
-                old_d1 = image["direct"].model_view(
-                    0, int(shapes["direct_nb"][0]))
-                old_f1 = image["failover"].model_view(
-                    0, int(shapes["failover_nb"][0]))
+                old_d1 = old_d.model_view(0, int(shapes["direct_nb"][0]))
+                old_f1 = old_f.model_view(0, int(shapes["failover_nb"][0]))
             else:
-                old_d1, old_f1 = image["direct"], image["failover"]
+                old_d1, old_f1 = old_d, old_f
             cfg = server.cfg
             lru1 = cfg.eviction == "lru"
             new_d, n_dir = elastic.rehash_cache(

@@ -8,35 +8,31 @@ the serve path feeds the touch buffer for deferred last-access bumps — and
 the cache table never leaves HBM except for the probed buckets
 (DESIGN.md §4).
 
-The kernels see every table **bucket-minor**: metadata as ``(W, N)`` and
-values as ``(D, W, N)`` (``N`` buckets). That is the device layout XLA
-gives the ``(N, W)`` / ``(N, W, D)`` tables on a TPU (bucket axis minor,
-so a narrow ``W`` or ``D`` is never padded to 128 lanes), and in it a
-query's bucket sits in one ``L = 128``-lane block that a DMA can address:
+The kernels read the metadata only. They see each metadata table
+**bucket-minor**, as ``(W, N)`` (``N`` buckets): the device layout XLA
+gives the ``(N, W)`` tables on a TPU (bucket axis minor, so a narrow ``W``
+is never padded to 128 lanes), and in it a query's bucket sits in one
+``L = 128``-lane block that a DMA can address:
 
-* phase 1 lands the ``(W, L)`` metadata block holding each query's bucket
-  (key_hi / key_lo / write_ts: one (8, 128) int32 tile each at W=8);
+* each query's ``(W, L)`` metadata block lands in VMEM (key_hi / key_lo /
+  write_ts: one (8, 128) int32 tile each at W=8);
 * a per-query lane pick packs the bucket's column of every block into
   ``(W, TQ)`` tiles with queries on lanes, and the key-compare / TTL /
-  first-valid-way select then runs ONCE, vectorized over the query tile;
-* the winning ways go VMEM→SMEM, and phase 2 lands each HIT query's
-  ``(D, W, L)`` value block, a chunk of queries at a time (as many as
-  ``VALUE_VMEM_BYTES`` holds), and picks the winning way's bucket column
-  out of it, giving a ``(D, TQ)`` value tile (misses fetch nothing and
-  come out zero). One way of a ``(W, L)`` tile is a single sublane, which
-  the chip's DMA cannot address, so the block carries all ``W`` ways:
-  a hit reads ``W * L`` times the value bytes it returns.
+  first-valid-way select then runs ONCE, vectorized over the query tile.
 
-Every pick is a select plus a max over the values' bits as int32, so a
-32-bit value comes out bit-identical to the oracle's gather whatever its
-bit pattern; a narrower float goes through float32, exact for every
-non-NaN value.
+The values are a flat ``(D, N * W)`` plane of entry columns (slot
+``(bucket, way)`` is column ``bucket * W + way``; ``core.cache``), and the
+wrappers gather each hit's column from it after the kernel, zeroing
+misses. The kernel cannot read that plane itself: a ``D``-sublane block of
+it (D=50 pads to 56) is not tile-aligned, so the chip's DMA refuses it.
+The gather copies bits, so every value comes out bit-identical to the
+oracle's whatever its bit pattern.
 
 * ``cache_probe_tiled`` (exported as ``cache_probe``): one table.
 * ``cache_probe_dual``: the direct AND failover tables for the same
   queries in a single kernel launch — one grid sweep, one shared
-  start/drain loop pair per phase for BOTH tables' DMAs — so
-  ``serve_step`` does not pay two full-batch kernel dispatches.
+  start/drain loop pair for BOTH tables' DMAs — so ``serve_step`` does not
+  pay two full-batch kernel dispatches.
 * ``cache_probe_dual_multi``: the dual probe over a stacked multi-model
   tier, each query's TTLs taken from its model's row of the policy table.
 * ``cache_probe_perquery``: the original one-query-per-grid-step kernel
@@ -62,9 +58,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TILE_Q = 128
 LANES = 128
-# VMEM for the phase-2 value blocks of all tiers: a chunk holds as many
-# queries' (D, W, L) blocks as fit, so a wider D takes fewer per chunk
-VALUE_VMEM_BYTES = 8 << 20
 
 # Python-level launch counters (one increment per wrapper call, i.e. per
 # kernel launch in eager mode / per trace under jit). Tests use these to
@@ -113,59 +106,12 @@ def _lane_block(n_buckets: int, interpret: bool) -> int:
     return lanes
 
 
-def _value_chunk(tq: int, tiers, interpret: bool) -> int:
-    """Queries per phase-2 chunk: as many (D, W, L) value blocks of every
-    tier as ``VALUE_VMEM_BYTES`` holds (budgeted at 4 bytes an element: a
-    narrower dtype pads its sublanes), a divisor of ``tq``, at least 1."""
-    per_query = sum(
-        val.shape[1] * val.shape[2] * _lane_block(val.shape[0], interpret) * 4
-        for *_, val in tiers)
-    chunk = max(1, min(tq, VALUE_VMEM_BYTES // per_query))
-    while tq % chunk:
-        chunk -= 1
-    return chunk
-
-
-def _bucket_minor(key_hi, key_lo, write_ts, values):
-    """One tier's tables as the kernels see them: (W, N) metadata and
-    (D, W, N) values."""
-    return key_hi.T, key_lo.T, write_ts.T, values.transpose(2, 1, 0)
-
-
-def _to_bits(x):
-    """int32 bits of float values: float32 as they are, a narrower float
-    through float32."""
-    return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
-
-
-def _from_bits(bits, dtype):
-    """Inverse of :func:`_to_bits`."""
-    return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(dtype)
-
-
-def _select_max(sel, bits, axes):
-    """The one selected element of int32 ``bits`` along ``axes``: any other
-    element is the int32 minimum, so the max returns the selected bits."""
-    picked = jnp.where(sel, bits, jnp.iinfo(jnp.int32).min)
-    *inner, last = sorted(axes, reverse=True)
-    for axis in inner:
-        picked = jnp.max(picked, axis=axis)
-    return jnp.max(picked, axis=last, keepdims=True)
-
-
 def _pick_lane(block, lane):
-    """Column ``lane`` (a scalar) of an int32 (R, L) block, as (R, 1)."""
+    """Column ``lane`` (a scalar) of an int32 (R, L) block, as (R, 1): any
+    other lane is the int32 minimum, so the max returns the picked one."""
     lanes = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-    return _select_max(lanes == lane, block, (1,))
-
-
-def _pick_way_lane(block, way, lane):
-    """Bits of way ``way`` of bucket column ``lane`` of a (D, W, L) value
-    block, as int32 (D, 1)."""
-    ways = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, block.shape, 2)
-    return _select_max((ways == way) & (lanes == lane), _to_bits(block),
-                       (1, 2))
+    picked = jnp.where(lanes == lane, block, jnp.iinfo(jnp.int32).min)
+    return jnp.max(picked, axis=1, keepdims=True)
 
 
 def _gather_columns(tq: int, blocks_of, lane_of, accs):
@@ -184,8 +130,8 @@ def _gather_columns(tq: int, blocks_of, lane_of, accs):
 def _match_tile(now, ttl, qhi, qlo, khi, klo, ts):
     """Vectorized metadata probe over a (W, TQ) tile (queries on lanes).
     Returns (hit, age, way) rows (1, TQ) — the hit way (-1 on miss) is both
-    the phase-2 value-fetch index and the coordinate the serve path feeds
-    the touch buffer."""
+    the value column the wrapper gathers and the coordinate the serve path
+    feeds the touch buffer."""
     match = (khi == qhi) & (klo == qlo)
     # TS_EMPTY lanes wrap negative here but never match a real key, so
     # `match` masks them out of `valid`/`age` below.
@@ -206,16 +152,11 @@ def _match_tile(now, ttl, qhi, qlo, khi, klo, ts):
 def _start_then_drain(tq: int, dmas):
     """Start ALL tile DMAs, then drain: the copies overlap each other (and,
     on hardware, the previous tile's output write-back). ``dmas(j)`` must
-    rebuild the same (copy, predicate) pairs on both passes; a copy with a
-    predicate (a scalar bool, None for always) is started and waited for
-    only where it holds."""
+    rebuild the same copies on both passes."""
     def on_each(op):
         def body(j, c):
-            for d, cond in dmas(j):
-                if cond is None:
-                    op(d)
-                else:
-                    pl.when(cond)(functools.partial(op, d))
+            for d in dmas(j):
+                op(d)
             return c
         return body
 
@@ -225,94 +166,44 @@ def _start_then_drain(tq: int, dmas):
 
 def _probe_body(tq: int, now, ttls, bkts, qhi, qlo, tiers, outs, scratch,
                 sems):
-    """Two-phase probe of ``len(tiers)`` tables for one query tile.
+    """Metadata probe of ``len(tiers)`` tables for one query tile.
 
     ``tiers[i]`` are tier i's bucket-minor HBM refs (key_hi, key_lo,
-    write_ts, values), ``bkts[i]`` its scalar-prefetched buckets,
-    ``ttls[i]`` its TTL (a scalar, or a (1, TQ) row of per-query TTLs),
-    ``outs[i]`` its (hit, value, age, way) output blocks and
-    ``scratch[i]`` its (meta (3, TQ, W, L), values (CHUNK, D, W, L), way
-    row (1, TQ) VMEM, way row (1, TQ) SMEM) buffers. ONE start/drain loop
-    pair per phase (per chunk in phase 2) lands every tier's blocks."""
+    write_ts), ``bkts[i]`` its scalar-prefetched buckets, ``ttls[i]`` its
+    TTL (a scalar, or a (1, TQ) row of per-query TTLs), ``outs[i]`` its
+    (hit, age, way) output blocks and ``scratch[i]`` its (3, TQ, W, L)
+    VMEM buffer. ONE start/drain loop pair lands every tier's blocks."""
     t = pl.program_id(0)
 
     def block_of(i, j):
         """(first lane of the block, lane within it) of query j, tier i."""
-        lanes = scratch[i][0].shape[-1]
+        lanes = scratch[i].shape[-1]
         b = bkts[i][t * tq + j]
         return pl.multiple_of(b - b % lanes, lanes), b % lanes
 
     def meta_dmas(j):
         dmas = []
-        for i, (tabs, (meta_s, *_)) in enumerate(zip(tiers, scratch)):
+        for i, (tabs, meta_s) in enumerate(zip(tiers, scratch)):
             start, _ = block_of(i, j)
             lanes = meta_s.shape[-1]
-            dmas += [(pltpu.make_async_copy(tab.at[:, pl.ds(start, lanes)],
-                                            meta_s.at[k, j], sems.at[i, k]),
-                      None) for k, tab in enumerate(tabs[:3])]
+            dmas += [pltpu.make_async_copy(tab.at[:, pl.ds(start, lanes)],
+                                           meta_s.at[k, j], sems.at[i, k])
+                     for k, tab in enumerate(tabs)]
         return dmas
 
     _start_then_drain(tq, meta_dmas)
 
-    hits = []
-    for i, (meta_s, _, way_v, way_s) in enumerate(scratch):
+    for i, meta_s in enumerate(scratch):
         n_ways = meta_s.shape[2]
         khi, klo, ts = _gather_columns(
             tq, lambda j, m=meta_s: (m[0, j], m[1, j], m[2, j]),
             lambda j, i=i: block_of(i, j)[1],
             [jnp.zeros((n_ways, tq), jnp.int32)] * 3)
         hit, age, way = _match_tile(now, ttls[i], qhi, qlo, khi, klo, ts)
-        hit_ref, _, age_ref, way_ref = outs[i]
+        hit_ref, age_ref, way_ref = outs[i]
         hit_ref[...] = hit
         age_ref[...] = age
         way_ref[...] = way
-        hits.append(hit)
-        # phase 2 indexes its DMAs with the ways, so they go to SMEM
-        way_v[...] = way
-        copy = pltpu.make_async_copy(way_v, way_s, sems.at[i, 3])
-        copy.start()
-        copy.wait()
-
-    # phase 2: each hit's whole (D, W, L) value block — the chip's DMA
-    # cannot address one way (one sublane) of a (W, L) tile — a chunk of
-    # queries at a time, then the hit way and bucket column are picked.
-    # A tier's copies share one semaphore, so a miss neither starts nor
-    # waits for one.
-    chunk = scratch[0][1].shape[0]
-    q_lane = jax.lax.broadcasted_iota(jnp.int32, (1, tq), 1)
-
-    def pick_chunk(c, accs):
-        def val_dmas(k):
-            j = c * chunk + k
-            dmas = []
-            for i, (tabs, (_, val_s, _, way_s)) in enumerate(
-                    zip(tiers, scratch)):
-                start, _ = block_of(i, j)
-                dmas.append((pltpu.make_async_copy(
-                    tabs[3].at[:, :, pl.ds(start, val_s.shape[-1])],
-                    val_s.at[k], sems.at[i, 3]), way_s[0, j] >= 0))
-            return dmas
-
-        def pick(k, accs):
-            j = c * chunk + k
-            return tuple(
-                jnp.where(q_lane == j, _pick_way_lane(
-                    val_s[k], way_s[0, j], block_of(i, j)[1]), acc)
-                for i, (acc, (_, val_s, _, way_s))
-                in enumerate(zip(accs, scratch)))
-
-        _start_then_drain(chunk, val_dmas)
-        return jax.lax.fori_loop(0, chunk, pick, accs)
-
-    vals = jax.lax.fori_loop(
-        0, tq // chunk, pick_chunk,
-        tuple(jnp.zeros((val_s.shape[1], tq), jnp.int32)
-              for _, val_s, _, _ in scratch))
-    for i, v in enumerate(vals):
-        out_ref = outs[i][1]
-        # a miss fetched and picked nothing: zero it
-        out_ref[...] = jnp.where(hits[i] == 1, _from_bits(v, out_ref.dtype),
-                                 0)
 
 
 def _policy_ttls(policy_ref, slot_v):
@@ -334,8 +225,8 @@ def _policy_ttls(policy_ref, slot_v):
 
 def _make_probe_kernel(tq: int, n_tiers: int, multi: bool):
     """Kernel over refs laid out as: buckets per tier, [policy], scalars
-    (scalar prefetch); q_hi, q_lo, [slots] rows; 4 bucket-minor tables
-    per tier; 4 outputs per tier; 4 scratch buffers per tier; DMA
+    (scalar prefetch); q_hi, q_lo, [slots] rows; 3 bucket-minor metadata
+    tables per tier; 3 outputs per tier; 1 scratch buffer per tier; DMA
     semaphores. ``scalars`` is [now, ttl per tier] — or [now] for the
     multi-model probe, whose TTLs come from the policy table."""
     def kernel(*refs):
@@ -350,9 +241,9 @@ def _make_probe_kernel(tq: int, n_tiers: int, multi: bool):
         policy = pop(1)[0] if multi else None
         scalars, qhi, qlo = pop(3)
         slots = pop(1)[0] if multi else None
-        tiers = [pop(4) for _ in range(n_tiers)]
-        outs = [pop(4) for _ in range(n_tiers)]
-        scratch = [pop(4) for _ in range(n_tiers)]
+        tiers = [pop(3) for _ in range(n_tiers)]
+        outs = [pop(3) for _ in range(n_tiers)]
+        scratch = pop(n_tiers)
         sems, = pop(1)
         if multi:
             ttls = _policy_ttls(policy, slots[...])
@@ -368,11 +259,10 @@ def _make_probe_kernel(tq: int, n_tiers: int, multi: bool):
 def _probe(tiers, q_hi, q_lo, buckets, scalars, slots=None, policy=None, *,
            name: str, tile_q: int, interpret: bool):
     """One ``pallas_call`` probing every tier in ``tiers`` — a tuple of
-    (key_hi, key_lo, write_ts, values) tables — for the same queries.
-    ``buckets`` holds each tier's (B,) bucket indices. ``name`` is the
-    kernel's stable name: the compiled program's instruction and a
-    profile's op event carry it. Returns one (hit, value, age, way) tuple
-    per tier."""
+    (key_hi, key_lo, write_ts) tables — for the same queries. ``buckets``
+    holds each tier's (B,) bucket indices. ``name`` is the kernel's stable
+    name: the compiled program's instruction and a profile's op event
+    carry it. Returns one (hit, age, way) tuple per tier."""
     B = q_hi.shape[0]
     tq = tile_q
     pad = (-B) % tq
@@ -387,25 +277,16 @@ def _probe(tiers, q_hi, q_lo, buckets, scalars, slots=None, policy=None, *,
     row = pl.BlockSpec((1, tq), lambda t, *_: (0, t))
     in_specs = [row] * len(rows)
     out_specs, out_shape, scratch, views = [], [], [], []
-    chunk = _value_chunk(tq, tiers, interpret)
     for tables in tiers:
-        khi, _, _, val = tables
-        N, W = khi.shape
-        D = val.shape[-1]
-        lanes = _lane_block(N, interpret)
-        views += _bucket_minor(*tables)
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 4
-        out_specs += [row, pl.BlockSpec((D, tq), lambda t, *_: (0, t)),
-                      row, row]
-        out_shape += [jax.ShapeDtypeStruct((1, Bp), jnp.int32),
-                      jax.ShapeDtypeStruct((D, Bp), val.dtype),
-                      jax.ShapeDtypeStruct((1, Bp), jnp.int32),
-                      jax.ShapeDtypeStruct((1, Bp), jnp.int32)]
-        scratch += [pltpu.VMEM((3, tq, W, lanes), jnp.int32),
-                    pltpu.VMEM((chunk, D, W, lanes), val.dtype),
-                    pltpu.VMEM((1, tq), jnp.int32),
-                    pltpu.SMEM((1, tq), jnp.int32)]
-    scratch.append(pltpu.SemaphoreType.DMA((len(tiers), 4)))
+        N, W = tables[0].shape
+        # each (N, W) table as the kernel sees it: bucket-minor (W, N)
+        views += [tab.T for tab in tables]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 3
+        out_specs += [row] * 3
+        out_shape += [jax.ShapeDtypeStruct((1, Bp), jnp.int32)] * 3
+        scratch.append(pltpu.VMEM((3, tq, W, _lane_block(N, interpret)),
+                                  jnp.int32))
+    scratch.append(pltpu.SemaphoreType.DMA((len(tiers), 3)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=(Bp // tq,),
@@ -417,26 +298,37 @@ def _probe(tiers, q_hi, q_lo, buckets, scalars, slots=None, policy=None, *,
         interpret=interpret,
         name=name,
     )(*prefetch, *rows, *views)
-    return tuple((hit[0, :B].astype(bool), val.T[:B], age[0, :B], way[0, :B])
-                 for hit, val, age, way in zip(*[iter(outs)] * 4))
+    return tuple((hit[0, :B].astype(bool), age[0, :B], way[0, :B])
+                 for hit, age, way in zip(*[iter(outs)] * 3))
+
+
+def _hit_values(values, value_buckets, ways: int, probed):
+    """The (hit, value, age, way) contract from a tier's (hit, age, way):
+    each hit's column ``value_buckets * ways + way`` of the (D, E) value
+    plane, zeros for a miss."""
+    hit, age, way = probed
+    cols = value_buckets * ways + jnp.maximum(way, 0)
+    vals = jnp.take(values, cols, axis=1, mode="clip").T
+    vals = jnp.where(hit[:, None], vals, jnp.zeros((), values.dtype))
+    return hit, vals, age, way
 
 
 def cache_probe_tiled(key_hi, key_lo, write_ts, values, q_hi, q_lo, buckets,
                       now_ms, ttl_ms, *, tile_q=None, interpret=None):
     """Tiled Pallas cache probe. Same contract as ref.cache_probe_ref.
 
-    key_hi/key_lo/write_ts: (Nb, W) int32; values: (Nb, W, D);
-    q_hi/q_lo/buckets: (B,). Returns (hit (B,) bool, value (B, D),
+    key_hi/key_lo/write_ts: (Nb, W) int32; values: the (D, Nb * W) value
+    plane; q_hi/q_lo/buckets: (B,). Returns (hit (B,) bool, value (B, D),
     age (B,), way (B,) int32 — the hit way, -1 on miss).
     Batch sizes that are not a multiple of ``tile_q`` are padded internally.
     """
     LAUNCHES["tiled"] += 1
-    out, = _probe(
-        ((key_hi, key_lo, write_ts, values),), q_hi, q_lo, (buckets,),
+    probed, = _probe(
+        ((key_hi, key_lo, write_ts),), q_hi, q_lo, (buckets,),
         jnp.asarray([now_ms, ttl_ms], jnp.int32), name="ercache_probe_tiled",
         tile_q=_pick_tile(q_hi.shape[0], tile_q),
         interpret=resolve_interpret(interpret))
-    return out
+    return _hit_values(values, buckets, key_hi.shape[1], probed)
 
 
 # public name: the tiled kernel IS the cache probe
@@ -460,13 +352,14 @@ def cache_probe_dual(d_key_hi, d_key_lo, d_write_ts, d_values,
     respective table.
     """
     LAUNCHES["dual"] += 1
-    return _probe(
-        ((d_key_hi, d_key_lo, d_write_ts, d_values),
-         (f_key_hi, f_key_lo, f_write_ts, f_values)),
+    probed_d, probed_f = _probe(
+        ((d_key_hi, d_key_lo, d_write_ts), (f_key_hi, f_key_lo, f_write_ts)),
         q_hi, q_lo, (buckets_d, buckets_f),
         jnp.asarray([now_ms, ttl_direct_ms, ttl_failover_ms], jnp.int32),
         name="ercache_probe_dual", tile_q=_pick_tile(q_hi.shape[0], tile_q),
         interpret=resolve_interpret(interpret))
+    return (_hit_values(d_values, buckets_d, d_key_hi.shape[1], probed_d),
+            _hit_values(f_values, buckets_f, f_key_hi.shape[1], probed_f))
 
 
 def cache_probe_dual_multi(d_key_hi, d_key_lo, d_write_ts, d_values,
@@ -476,29 +369,40 @@ def cache_probe_dual_multi(d_key_hi, d_key_lo, d_write_ts, d_values,
     """Probe the pooled direct + failover tiers of a multi-model stack for a
     MIXED-model query batch in ONE launch.
 
-    ``d_*``/``f_*`` are the pooled (M*Nb, W[, D]) views of the stacked
-    tables, ``slots`` (B,) assigns each query its model, ``buckets_*``
-    already carry the slot offset (``core.cache.pooled_buckets``), and
-    ``policy`` is the (M, 2) int32 [direct_ttl, failover_ttl] table —
-    scalar-prefetched so each query's freshness check uses its own model's
-    TTLs. Returns ((hit_d, value_d, age_d, way_d), (hit_f, value_f,
-    age_f, way_f)), each half bit-identical to a per-model jnp-oracle loop.
+    ``d_*``/``f_*`` are the pooled (M*Nb, W) metadata views of the
+    stacked tables and their stacked value planes (models interleaved
+    within a bucket, ``core.cache.MultiCacheState``), ``slots`` (B,)
+    assigns each query its model, ``buckets_*`` already carry the slot
+    offset (``core.cache.pooled_buckets``), and ``policy`` is the (M, 2)
+    int32 [direct_ttl, failover_ttl] table — scalar-prefetched so each
+    query's freshness check uses its own model's TTLs. Returns ((hit_d,
+    value_d, age_d, way_d), (hit_f, value_f, age_f, way_f)), each half
+    bit-identical to a per-model jnp-oracle loop.
     """
+    from repro.core.cache import pooled_value_buckets
+
     LAUNCHES["dual_multi"] += 1
-    return _probe(
-        ((d_key_hi, d_key_lo, d_write_ts, d_values),
-         (f_key_hi, f_key_lo, f_write_ts, f_values)),
+    probed_d, probed_f = _probe(
+        ((d_key_hi, d_key_lo, d_write_ts), (f_key_hi, f_key_lo, f_write_ts)),
         q_hi, q_lo, (buckets_d, buckets_f),
         jnp.asarray([now_ms], jnp.int32), slots,
         jnp.asarray(policy, jnp.int32), name="ercache_probe_dual_multi",
         tile_q=_pick_tile(q_hi.shape[0], tile_q),
         interpret=resolve_interpret(interpret))
+    M = policy.shape[0]
+    return tuple(
+        _hit_values(vals, pooled_value_buckets(b, M, khi.shape[0] // M),
+                    khi.shape[1], probed)
+        for vals, b, khi, probed in ((d_values, buckets_d, d_key_hi,
+                                      probed_d),
+                                     (f_values, buckets_f, f_key_hi,
+                                      probed_f)))
 
 
 # ----------------------------------------------- per-query (legacy baseline)
 def _perquery_kernel(bucket_ref, scalars_ref,            # scalar prefetch
-                     khi_ref, klo_ref, ts_ref, val_ref, qhi_ref, qlo_ref,
-                     hit_ref, out_ref, age_ref):
+                     khi_ref, klo_ref, ts_ref, qhi_ref, qlo_ref,
+                     hit_ref, age_ref, way_ref):
     now = scalars_ref[0]
     ttl = scalars_ref[1]
     khi = khi_ref[0]                       # (W,)
@@ -510,57 +414,44 @@ def _perquery_kernel(bucket_ref, scalars_ref,            # scalar prefetch
     valid = match & fresh
     hit = jnp.any(valid)
     first = valid & (jnp.cumsum(valid.astype(jnp.int32)) == 1)
-    val = jnp.sum(jnp.where(first[:, None], val_ref[0], 0.0), axis=0)
+    way = jnp.sum(jnp.where(first, jnp.arange(khi.shape[0]), 0))
     age = jnp.sum(jnp.where(first, now - ts, 0))  # erlint: allow[ER004]
     hit_ref[0] = hit.astype(jnp.int32)
-    out_ref[0] = val.astype(out_ref.dtype)
     age_ref[0] = jnp.where(hit, age, jnp.int32(-1))
+    way_ref[0] = jnp.where(hit, way, jnp.int32(-1))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _cache_probe_perquery(key_hi, key_lo, write_ts, values, q_hi, q_lo,
+def _cache_probe_perquery(key_hi, key_lo, write_ts, q_hi, q_lo,
                           buckets, now_ms, ttl_ms, *, interpret: bool):
     B = q_hi.shape[0]
-    Nb, W = key_hi.shape
-    D = values.shape[-1]
+    W = key_hi.shape[1]
     scalars = jnp.asarray([now_ms, ttl_ms], jnp.int32)
-
+    meta = pl.BlockSpec((1, W), lambda i, b, s: (b[i], 0))
+    one = pl.BlockSpec((1,), lambda i, b, s: (i,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, W), lambda i, b, s: (b[i], 0)),
-            pl.BlockSpec((1, W), lambda i, b, s: (b[i], 0)),
-            pl.BlockSpec((1, W), lambda i, b, s: (b[i], 0)),
-            pl.BlockSpec((1, W, D), lambda i, b, s: (b[i], 0, 0)),
-            pl.BlockSpec((1,), lambda i, b, s: (i,)),
-            pl.BlockSpec((1,), lambda i, b, s: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i, b, s: (i,)),
-            pl.BlockSpec((1, D), lambda i, b, s: (i, 0)),
-            pl.BlockSpec((1,), lambda i, b, s: (i,)),
-        ],
+        in_specs=[meta] * 3 + [one] * 2,
+        out_specs=[one] * 3,
     )
-    hit, out, age = pl.pallas_call(
+    hit, age, way = pl.pallas_call(
         _perquery_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B, D), values.dtype),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32)] * 3,
         interpret=interpret,
         name="ercache_probe_perquery",
-    )(buckets, scalars, key_hi, key_lo, write_ts, values, q_hi, q_lo)
-    return hit.astype(bool), out, age
+    )(buckets, scalars, key_hi, key_lo, write_ts, q_hi, q_lo)
+    return hit.astype(bool), age, way
 
 
 def cache_probe_perquery(key_hi, key_lo, write_ts, values, q_hi, q_lo,
                          buckets, now_ms, ttl_ms, *, interpret=None):
     """One-query-per-grid-step probe (pre-tiling implementation). Same
-    contract as ``cache_probe_tiled``; kept as the benchmark baseline."""
+    contract as ``cache_probe_tiled`` less the way; kept as the benchmark
+    baseline."""
     LAUNCHES["perquery"] += 1
-    return _cache_probe_perquery(key_hi, key_lo, write_ts, values, q_hi,
-                                 q_lo, buckets, now_ms, ttl_ms,
-                                 interpret=resolve_interpret(interpret))
+    probed = _cache_probe_perquery(key_hi, key_lo, write_ts, q_hi, q_lo,
+                                   buckets, now_ms, ttl_ms,
+                                   interpret=resolve_interpret(interpret))
+    return _hit_values(values, buckets, key_hi.shape[1], probed)[:3]

@@ -20,8 +20,9 @@ def cache_probe_ref(key_hi, key_lo, write_ts, values, q_hi, q_lo, buckets,
     """Set-associative TTL probe (same contract as core.cache.lookup, with
     bucket indices precomputed — the kernel's scalar-prefetch input).
 
-    key_hi/key_lo/write_ts: (Nb, W); values: (Nb, W, D);
-    q_hi/q_lo/buckets: (B,). Returns (hit (B,) bool, value (B, D),
+    key_hi/key_lo/write_ts: (Nb, W); values: the (D, Nb * W) value plane
+    (slot (bucket, way) is column bucket * W + way); q_hi/q_lo/buckets:
+    (B,). Returns (hit (B,) bool, value (B, D),
     age (B,) int32 — -1 on miss, way (B,) int32 — hit way, -1 on miss).
     """
     k_hi = key_hi[buckets]                   # (B, W)
@@ -33,7 +34,7 @@ def cache_probe_ref(key_hi, key_lo, write_ts, values, q_hi, q_lo, buckets,
     valid = match & fresh
     hit = jnp.any(valid, axis=-1)
     way = jnp.argmax(valid, axis=-1)
-    out = values[buckets, way]
+    out = values[:, buckets * key_hi.shape[1] + way].T
     out = jnp.where(hit[:, None], out, 0.0)
     # erlint: allow[ER004] — miss lanes forced to -1 by the hit mask
     age = jnp.where(hit, jnp.int32(now_ms) - ts[jnp.arange(buckets.shape[0]),

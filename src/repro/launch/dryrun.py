@@ -359,12 +359,12 @@ def run_ercache_cell(arch: str = "tinyllama-1.1b", batch: int = 4096,
         direct=type(state_abs.direct)(
             key_hi=P(("data", "model")), key_lo=P(("data", "model")),
             write_ts=P(("data", "model")),
-            values=P(("data", "model"), None, None),
+            value_plane=P(None, ("data", "model")),
             last_access_ts=P(("data", "model"))),
         failover=type(state_abs.failover)(
             key_hi=P(("data", "model")), key_lo=P(("data", "model")),
             write_ts=P(("data", "model")),
-            values=P(("data", "model"), None, None),
+            value_plane=P(None, ("data", "model")),
             last_access_ts=P(("data", "model"))),
         writebuf=jax.tree_util.tree_map(lambda _: P(), state_abs.writebuf),
         touchbuf=jax.tree_util.tree_map(lambda _: P(), state_abs.touchbuf),

@@ -490,12 +490,8 @@ def cache_tier_specs(state) -> Any:
     def rep(tree):
         return jax.tree_util.tree_map(lambda _: P(), tree)
 
-    def table(tree):
-        spec = coll.cache_pspec(tree)
-        return jax.tree_util.tree_map(lambda _: spec, tree)
-
-    return state._replace(direct=table(state.direct),
-                          failover=table(state.failover),
+    return state._replace(direct=coll.cache_pspec(state.direct),
+                          failover=coll.cache_pspec(state.failover),
                           writebuf=rep(state.writebuf),
                           touchbuf=rep(state.touchbuf),
                           budget=rep(state.budget))

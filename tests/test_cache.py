@@ -154,3 +154,84 @@ def test_property_capacity_never_exceeded(data):
                          jnp.ones((len(ids), 2)), now_ms=t, ttl_ms=MIN)
         occupied = int(float(state.occupancy()) * state.capacity + 0.5)
         assert occupied <= min(len(seen), state.capacity)
+
+
+# ------------------------------------------------ the flat value plane
+def _bits(rng, shape):
+    """float32 values of arbitrary bit patterns (NaN payloads, -0.0, inf)."""
+    return rng.integers(0, 1 << 32, size=shape,
+                        dtype=np.uint32).view(np.float32)
+
+
+@pytest.mark.parametrize("fo_buckets,fo_ways", [(8, 2), (4, 4)])
+def test_insert_dual_value_plane_matches_numpy_replay(fo_buckets, fo_ways):
+    """A sequence of insert_dual batches with duplicate keys, crowded
+    buckets (write-drop losers) and overwrites: both tiers' value planes
+    equal a numpy replay of each batch's plan through the (N, W, D) view,
+    bit for bit, and the plane is that view's flat entry columns."""
+    rng = np.random.default_rng(7)
+    dim = 5
+    direct, failover = C.init_cache(8, 2, dim), C.init_cache(fo_buckets,
+                                                              fo_ways, dim)
+    want = {"d": np.zeros((8, 2, dim), np.float32),
+            "f": np.zeros((fo_buckets, fo_ways, dim), np.float32)}
+    dropped = overwritten = 0
+    for t in range(6):
+        ids = rng.integers(0, 24, size=20)          # duplicates in-batch
+        k = keys_of(ids)
+        vals = _bits(rng, (20, dim))
+        mask = jnp.asarray(rng.uniform(size=20) < 0.85)
+        now = t * 1000
+        plans = {"d": C.plan_insert(direct, k, now, MIN, mask),
+                 "f": C.plan_insert(failover, k, now, 10 * MIN, mask)}
+        direct, failover = C.insert_dual(direct, failover, k,
+                                         jnp.asarray(vals), now, MIN,
+                                         10 * MIN, write_mask=mask)
+        for tier, (winner, bucket, way) in plans.items():
+            winner, bucket, way = map(np.asarray, (winner, bucket, way))
+            dropped += int((np.asarray(mask) & ~winner).sum())
+            for i in np.nonzero(winner)[0]:
+                overwritten += bool(want[tier][bucket[i], way[i]].any())
+                want[tier][bucket[i], way[i]] = vals[i]
+    assert dropped and overwritten
+    for tier, got in (("d", direct), ("f", failover)):
+        n, w, _ = want[tier].shape
+        assert got.value_plane.shape == (dim, n * w)
+        assert (np.asarray(got.values).tobytes()
+                == want[tier].tobytes())
+        assert (np.asarray(got.value_plane).tobytes()
+                == np.ascontiguousarray(
+                    want[tier].reshape(n * w, dim).T).tobytes())
+
+
+@pytest.mark.parametrize("multi", [False, True], ids=["single", "stacked"])
+def test_table_image_round_trip(multi):
+    """table_image / from_image: the (buckets, ways, dim) view the
+    snapshots store converts back to the same value plane, bit for bit;
+    a stacked tier's models interleave within each bucket."""
+    rng = np.random.default_rng(3)
+    state = (C.init_multi_cache([8, 4, 8], 2, 3) if multi
+             else C.init_cache(8, 2, 3))
+    state = state._replace(value_plane=jnp.asarray(
+        _bits(rng, state.value_plane.shape)))
+    image = C.table_image(state)
+    back = C.from_image(image)
+    assert type(back) is type(state)
+    assert (np.asarray(back.value_plane).tobytes()
+            == np.asarray(state.value_plane).tobytes())
+    values = np.asarray(image.values)
+    plane = np.asarray(state.value_plane)
+    if multi:
+        M, nb, w, _ = values.shape
+        for m in range(M):
+            for b in range(nb):
+                np.testing.assert_array_equal(
+                    values[m, b].view(np.uint32),
+                    plane[:, (b * M + m) * w:(b * M + m + 1) * w]
+                    .T.view(np.uint32))
+            view = state.model_view(m)
+            assert (np.asarray(view.values).tobytes()
+                    == values[m].tobytes())
+    else:
+        np.testing.assert_array_equal(
+            values.reshape(-1, 3).view(np.uint32), plane.T.view(np.uint32))

@@ -24,7 +24,8 @@ def test_cache_probe_sweep(dim, ways, rng):
     key_hi = jnp.asarray(rng.integers(0, 30, (Nb, ways)), jnp.int32)
     key_lo = jnp.asarray(rng.integers(0, 30, (Nb, ways)), jnp.int32)
     ts = jnp.asarray(rng.integers(0, 1000, (Nb, ways)), jnp.int32)
-    vals = jnp.asarray(rng.standard_normal((Nb, ways, dim)), jnp.float32)
+    # the (D, Nb * W) value plane: slot (bucket, way) is one column
+    vals = jnp.asarray(rng.standard_normal((dim, Nb * ways)), jnp.float32)
     buckets = jnp.asarray(rng.integers(0, Nb, (B,)), jnp.int32)
     way_pick = rng.integers(0, ways, B)
     q_hi = key_hi[buckets, way_pick]
@@ -52,7 +53,7 @@ def test_cache_probe_matches_core_lookup(rng):
     pk = Key64.from_int(probe_ids)
     want = C.lookup(state, pk, now_ms=1000, ttl_ms=60_000)
     got = cache_probe(state.key_hi, state.key_lo, state.write_ts,
-                      state.values, pk.hi, pk.lo,
+                      state.value_plane, pk.hi, pk.lo,
                       bucket_index(pk, state.n_buckets), 1000, 60_000)
     np.testing.assert_array_equal(got[0], want.hit)
     np.testing.assert_allclose(got[1], want.values, atol=1e-6)

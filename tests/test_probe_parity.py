@@ -108,7 +108,7 @@ def test_tiled_probe_tile_size_invariance(tile_q, rng):
     b = bucket_index(k, state.n_buckets)
     want = C.lookup(state, k, now_ms=2 * MIN, ttl_ms=MIN)
     hit, vals, age, way = pk.cache_probe_tiled(
-        state.key_hi, state.key_lo, state.write_ts, state.values,
+        state.key_hi, state.key_lo, state.write_ts, state.value_plane,
         k.hi, k.lo, b, 2 * MIN, MIN, tile_q=tile_q)
     assert_lookup_equal(C.LookupResult(hit, vals, age, bucket=b, way=way),
                         want)
@@ -129,7 +129,7 @@ def test_tiled_matches_perquery_kernel(rng):
     ids = query_mix(rng, fresh_ids, stale_ids, 48)
     k = keys_of(ids)
     b = bucket_index(k, state.n_buckets)
-    args = (state.key_hi, state.key_lo, state.write_ts, state.values,
+    args = (state.key_hi, state.key_lo, state.write_ts, state.value_plane,
             k.hi, k.lo, b, 2 * MIN, MIN)
     got = pk.cache_probe_tiled(*args)
     want = pk.cache_probe_perquery(*args)   # legacy 3-output contract
